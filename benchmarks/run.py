@@ -46,6 +46,9 @@ def main() -> None:
                          "the committed baseline (see benchmarks.perf)")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     if args.bench:
         from benchmarks import perf
         perf.run_bench()

@@ -2,7 +2,8 @@
 where gradient buckets are LCMP-routed over candidate route programs,
 then fail a route and watch the lazy re-bind (fast-failover).
 
-Runs in a subprocess with 8 simulated devices (2 pods x 2 data x 2 model).
+A CPU demo: it runs in a subprocess on 8 virtual CPU devices (2 pods x
+2 data x 2 model), so the parent never initializes JAX.
 
   PYTHONPATH=src python examples/multipod_grad_routes.py
 """
@@ -13,7 +14,6 @@ import sys
 SCRIPT = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import repro  # installs the jax.shard_map forward-compat alias on jax 0.4.x
 import jax, jax.numpy as jnp, numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
@@ -30,12 +30,13 @@ def reduce_fn(g):
 f = shard_map(reduce_fn, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
               check_vma=False)
 out = jax.jit(f)(jax.tree.map(lambda x: x, grads))
-print("reduced ok:", all(bool(jnp.all(v == v[0, 0])) for v in out.values()))
+out = jax.tree.map(np.asarray, out)
+print("reduced ok:", all(bool((v == v[0, 0]).all()) for v in out.values()))
 
 # kill route 0 (telemetry marks the direct all-reduce path dead)
 lc.set_route_liveness([False, True, True])
 print("route binding (route0 dead):", lc.schedule_buckets(ids))
 '''
-env = dict(os.environ, PYTHONPATH="src")
+env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
 subprocess.run([sys.executable, "-c", SCRIPT], env=env, check=True)
 print("multipod_grad_routes OK")

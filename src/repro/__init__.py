@@ -1,25 +1,1 @@
-"""repro — LCMP reproduction package.
-
-Importing ``repro`` installs one forward-compat alias: newer jax exposes
-``jax.shard_map(..., check_vma=)`` at the top level, while the pinned
-jax 0.4.x only ships ``jax.experimental.shard_map.shard_map(...,
-check_rep=)``. Call sites (and the test suite) use the new spelling, so
-bridge it here once instead of try/excepting at every import site.
-"""
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f=None, *, mesh, in_specs, out_specs,
-                          check_vma=None, check_rep=None, **kw):
-        if check_rep is None:
-            check_rep = True if check_vma is None else check_vma
-        if f is None:
-            return lambda g: _compat_shard_map(
-                g, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_rep, **kw)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep, **kw)
-
-    _jax.shard_map = _compat_shard_map
+"""repro — LCMP reproduction package."""

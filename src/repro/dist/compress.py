@@ -8,7 +8,8 @@ with Np = N rounded up to a 1024 multiple, so the wire carries
 DCI long haul (DESIGN §5; the ``lcmp_int8`` train path).
 
 Quantization runs through the Pallas kernel ``repro.kernels.qsr_int8``
-(blockwise amax, stochastic rounding from caller-supplied counter bits,
+via ``repro.kernels.ops`` (compiled on a TPU, interpreted elsewhere;
+blockwise amax, stochastic rounding from caller-supplied counter bits,
 so the wire format is deterministic and testable). Error feedback
 (``encode_ef``) returns the representation residual so the caller can
 fold it into the *next* step's gradient, making the compression
@@ -21,7 +22,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from repro.core.select import fmix32
-from repro.kernels.qsr_int8 import BLOCK, qsr_dequant, qsr_int8
+from repro.kernels import ops
+from repro.kernels.qsr_int8 import BLOCK
 
 
 class Wire(NamedTuple):
@@ -50,12 +52,12 @@ def encode(x: jnp.ndarray, *, seed=0, salt=0) -> Wire:
     xf = x.astype(jnp.float32)
     if np_ != n:
         xf = jnp.concatenate([xf, jnp.zeros((np_ - n,), jnp.float32)])
-    q, scales = qsr_int8(xf, rand_bits(np_, seed, salt))
+    q, scales = ops.qsr_int8(xf, rand_bits(np_, seed, salt))
     return Wire(q=q, scales=scales, orig_len=n)
 
 
 def decode(w: Wire) -> jnp.ndarray:
-    return qsr_dequant(w.q, w.scales)[: w.orig_len]
+    return ops.qsr_dequant(w.q, w.scales)[: w.orig_len]
 
 
 def wire_bytes(w: Wire) -> int:

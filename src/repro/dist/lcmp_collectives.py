@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import compress as comp
-from repro.kernels.qsr_int8 import BLOCK, qsr_dequant, qsr_int8
+from repro.kernels import ops
+from repro.kernels.qsr_int8 import BLOCK
 
 # Candidate inter-pod route programs (one-way propagation us, capacity
 # Gbps): direct DCI, fallback DCI, transit-pod detour.
@@ -193,19 +194,19 @@ def _reduce_flat_int8(seg: jnp.ndarray, axis, n: int,
         seg = jnp.concatenate([seg, jnp.zeros((mp - m,), jnp.float32)])
     me = jax.lax.axis_index(axis)
 
-    q, s = qsr_int8(seg, comp.rand_bits(mp, seed, salt=me))
+    q, s = ops.qsr_int8(seg, comp.rand_bits(mp, seed, salt=me))
     q2 = jax.lax.all_to_all(q.reshape(n, chunk), axis,
                             split_axis=0, concat_axis=0, tiled=True)
     s2 = jax.lax.all_to_all(s.reshape(n, chunk // BLOCK), axis,
                             split_axis=0, concat_axis=0, tiled=True)
-    part = qsr_dequant(q2.reshape(-1), s2.reshape(-1)).reshape(n, chunk)
+    part = ops.qsr_dequant(q2.reshape(-1), s2.reshape(-1)).reshape(n, chunk)
     mean_chunk = part.mean(0)
 
-    qm, sm = qsr_int8(mean_chunk, comp.rand_bits(chunk, seed ^ 0x5851F42D,
-                                                 salt=me))
+    qm, sm = ops.qsr_int8(mean_chunk,
+                          comp.rand_bits(chunk, seed ^ 0x5851F42D, salt=me))
     qg = jax.lax.all_gather(qm, axis, tiled=True)
     sg = jax.lax.all_gather(sm, axis, tiled=True)
-    return qsr_dequant(qg, sg)[:m]
+    return ops.qsr_dequant(qg, sg)[:m]
 
 
 def lcmp_pod_reduce(tree, axis, compress: bool = False):

@@ -73,7 +73,7 @@ def _cong_kernel(qcur_ref, qprev_ref, trend_ref, dur_ref,
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def cong_update(state: CongState, queue_cells: jnp.ndarray, now_us,
                 tables: SwitchTables, params: CongParams = CongParams(),
-                interpret: bool = True):
+                *, interpret: bool):
     """Fleet monitor tick. state fields (N,); queue_cells (N,) int32 cells.
     Returns (new CongState, c_cong (N,) int32)."""
     n = state.queue_cur.shape[0]

@@ -92,7 +92,7 @@ def _decide_kernel(fid_ref, cpath_ref, ccong_ref, valid_ref, out_ref, *,
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def lcmp_decide(flow_ids: jnp.ndarray, c_path: jnp.ndarray, c_cong: jnp.ndarray,
                 valid: jnp.ndarray, params: SelectParams = SelectParams(),
-                interpret: bool = True) -> jnp.ndarray:
+                *, interpret: bool) -> jnp.ndarray:
     """Batched LCMP decision. flow_ids (F,) uint32; c_path/c_cong/valid
     (F, P) with P <= 8. Returns (F,) int32 candidate indices (-1: none)."""
     F, P = c_path.shape

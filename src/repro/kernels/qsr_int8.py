@@ -2,9 +2,15 @@
 
 The framework-side hot spot: cross-pod gradient buckets are compressed
 4x (f32 -> int8 + per-1024-element f32 scale) before the long-haul
-all-reduce (DCI ~50 GB/s/link vs 819 GB/s HBM — see DESIGN §5). Error
-feedback lives in repro.dist.compress; this kernel is the bandwidth-bound
-inner loop, tiled (8,128) in VMEM so quantization streams at HBM rate.
+all-reduce (DCI ~50 GB/s/link vs 819 GB/s HBM). Error feedback lives in
+repro.dist.compress; this kernel is the bandwidth-bound inner loop.
+
+Layout: the flat vector is viewed as ``(g, 1024)``, one scale group per
+row, and each grid step takes ``GROUPS`` rows. The per-row amax is a
+lane reduction; the scales leave the kernel lane-dense as a ``(1, g)``
+row (one transpose per step moves the ``(GROUPS, 1)`` column onto the
+lanes). Every block is either tile-aligned or the whole array, which is
+what the TPU compiler accepts (a ``(1, 1)`` scale block is refused).
 
 Randomness comes in as a uint32 tensor (counter-based bits generated by
 the caller's jax PRNG) — keeps the kernel deterministic and testable.
@@ -14,66 +20,97 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu
 
-BLOCK = 1024            # elements per scale group = one (8,128) VMEM tile
-_TILE = (8, 128)
+BLOCK = 1024            # elements per scale group = one row of the view
+GROUPS = 128            # scale groups per grid step (lane width of scales)
+
+
+def _col_to_row(col):
+    """(G, 1) -> (1, G): broadcast over 128 lanes, transpose, take row 0."""
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[0:1, :]
+
+
+def _row_to_col(row):
+    """(1, G) -> (G, 1), the inverse of ``_col_to_row``."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, 0:1]
 
 
 def _quant_kernel(x_ref, bits_ref, q_ref, scale_ref):
-    x = x_ref[...]                                   # (8,128) f32
-    amax = jnp.max(jnp.abs(x))
+    x = x_ref[...]                                   # (G, 1024) f32
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
     inv = jnp.where(amax > 0, 127.0 / amax, 0.0)
     y = x * inv
-    u = (bits_ref[...] >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # 24 random bits -> [0, 1); via int32, as Mosaic has no u32 -> f32
+    # cast (the value is below 2**24, so both casts are exact)
+    u = ((bits_ref[...] >> 8).astype(jnp.int32).astype(jnp.float32)
+         * (1.0 / (1 << 24)))
     q = jnp.clip(jnp.floor(y + u), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
-    scale_ref[0, 0] = amax / 127.0
+    scale_ref[...] = _col_to_row(amax / 127.0)
 
 
 def _dequant_kernel(q_ref, scale_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[0, 0]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * _row_to_col(scale_ref[...])
+
+
+def _tiling(g: int):
+    """Rows per step and the padded row count: a single step when the
+    whole vector fits one (a block equal to the array is always legal),
+    else ``GROUPS`` rows per step over ``g`` padded to a multiple."""
+    if g <= GROUPS:
+        return g, g
+    return GROUPS, -(-g // GROUPS) * GROUPS
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def qsr_int8(x: jnp.ndarray, rand_bits: jnp.ndarray, interpret: bool = True):
+def qsr_int8(x: jnp.ndarray, rand_bits: jnp.ndarray, *, interpret: bool):
     """x (N,) f32, rand_bits (N,) uint32, N % 1024 == 0.
     Returns (q (N,) int8, scales (N/1024,) f32)."""
     n = x.shape[0]
     assert n % BLOCK == 0
     g = n // BLOCK
-    x2 = x.reshape(g * 8, 128)
-    b2 = rand_bits.reshape(g * 8, 128)
+    rows, gp = _tiling(g)
+    x2 = jnp.pad(x.reshape(g, BLOCK), ((0, gp - g), (0, 0)))
+    b2 = jnp.pad(rand_bits.reshape(g, BLOCK), ((0, gp - g), (0, 0)))
     q, scale = pl.pallas_call(
         _quant_kernel,
-        grid=(g,),
-        in_specs=[pl.BlockSpec(_TILE, lambda i: (i, 0), memory_space=pltpu.VMEM),
-                  pl.BlockSpec(_TILE, lambda i: (i, 0), memory_space=pltpu.VMEM)],
-        out_specs=[pl.BlockSpec(_TILE, lambda i: (i, 0), memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.VMEM)],
-        out_shape=[jax.ShapeDtypeStruct((g * 8, 128), jnp.int8),
-                   jax.ShapeDtypeStruct((g, 1), jnp.float32)],
+        grid=(gp // rows,),
+        in_specs=[pl.BlockSpec((rows, BLOCK), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)] * 2,
+        out_specs=[pl.BlockSpec((rows, BLOCK), lambda i: (i, 0),
+                                memory_space=pltpu.VMEM),
+                   pl.BlockSpec((1, rows), lambda i: (0, i),
+                                memory_space=pltpu.VMEM)],
+        out_shape=[jax.ShapeDtypeStruct((gp, BLOCK), jnp.int8),
+                   jax.ShapeDtypeStruct((1, gp), jnp.float32)],
         interpret=interpret,
         name="qsr_int8",
     )(x2, b2)
-    return q.reshape(n), scale[:, 0]
+    return q[:g].reshape(n), scale[0, :g]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def qsr_dequant(q: jnp.ndarray, scales: jnp.ndarray, interpret: bool = True):
+def qsr_dequant(q: jnp.ndarray, scales: jnp.ndarray, *, interpret: bool):
     """Inverse transform: (N,) int8 + (N/1024,) f32 -> (N,) f32."""
     n = q.shape[0]
     g = n // BLOCK
+    rows, gp = _tiling(g)
+    q2 = jnp.pad(q.reshape(g, BLOCK), ((0, gp - g), (0, 0)))
+    s2 = jnp.pad(scales.reshape(1, g), ((0, 0), (0, gp - g)))
     x = pl.pallas_call(
         _dequant_kernel,
-        grid=(g,),
-        in_specs=[pl.BlockSpec(_TILE, lambda i: (i, 0), memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(_TILE, lambda i: (i, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((g * 8, 128), jnp.float32),
+        grid=(gp // rows,),
+        in_specs=[pl.BlockSpec((rows, BLOCK), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, rows), lambda i: (0, i),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((rows, BLOCK), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((gp, BLOCK), jnp.float32),
         interpret=interpret,
         name="qsr_dequant",
-    )(q.reshape(g * 8, 128), scales.reshape(g, 1))
-    return x.reshape(n)
+    )(q2, s2)
+    return x[:g].reshape(n)

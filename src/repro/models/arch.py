@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
 
@@ -274,7 +275,24 @@ def forward(params, cfg: ArchConfig, tokens, *, extra=None):
 
     ``extra``: family-specific stub inputs — vlm: (B,n_patches,D) patch
     embeddings; encdec: (B,enc_seq,D) precomputed frame embeddings.
+
+    The layers leave intermediate shardings to the compiler's
+    propagation. On a mesh whose axes are Explicit (``jax.make_mesh``'s
+    default) every op would need a stated output sharding (the embedding
+    gather alone would map ``data`` twice: batch and FSDP feature dim), so
+    the forward runs with those axes made Auto; the logits keep the
+    tokens' batch sharding.
     """
+    sharding = jax.typeof(tokens).sharding
+    if not sharding.mesh.explicit_axes:  # reprolint: ignore[TRC002] static mesh metadata
+        return _forward(params, cfg, tokens, extra)
+    return jax.sharding.auto_axes(
+        lambda p, t, e: _forward(p, cfg, t, e),
+        out_sharding=sharding.update(spec=P(*sharding.spec, None)),
+    )(params, tokens, extra)
+
+
+def _forward(params, cfg: ArchConfig, tokens, extra):
     B, S = tokens.shape
     x = params["embed"][tokens].astype(cfg.adt)
     if cfg.family == "dense" and cfg.name.startswith("gemma"):

@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import repro  # noqa: F401  (installs the jax.shard_map forward-compat alias)
 from repro.launch.mesh import make_host_mesh
 from repro.netsim import engine as enginemod
 from repro.netsim import fluid, metrics, sanitize
@@ -90,6 +89,11 @@ class SweepReport:
     num_groups: int
     wall_s: float
     group_cells: List[int]     # cells per compiled group
+    # batched path only: host seconds building worlds, flow tables and
+    # padded cell stacks, and seconds in the jitted group calls (trace,
+    # compile and run, each call ended by block_until_ready)
+    build_s: Optional[float] = None
+    device_s: Optional[float] = None
 
     def __iter__(self):
         return iter(self.results)
@@ -260,7 +264,9 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
 
     results: List[Optional[CellResult]] = [None] * len(specs)
     group_cells: List[int] = []
+    build_s = device_s = 0.0
     for (topology, cfg), idxs in groups.items():
+        t_build = time.perf_counter()
         scen, table = build_world(topology)
         eng = enginemod.get_engine(cfg.engine)
         # narrow the dynamic dispatch to the policies actually present
@@ -277,7 +283,10 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
             arrs, st = eng.build(table, flows, cell_cfg)
             built.append((flows, arrs, st))
 
+        build_s += time.perf_counter() - t_build
+
         for chunk, chunk_idxs in _chunk_by_flows(built, idxs, max_pad_frac):
+            t_build = time.perf_counter()
             group_cells.append(len(chunk))
             Fmax = max(a.f_arr_us.shape[0] for _, a, _ in chunk)
             Amax = max(a.arrivals.shape[1] for _, a, _ in chunk)
@@ -304,7 +313,11 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
             mode = batch_mode
             if mode == "auto":
                 mode = "vmap" if Fmax <= _VMAP_MAX_FLOWS else "map"
-            final = _group_runner(shared, cfg, mesh, mode)(cells, states)
+            build_s += time.perf_counter() - t_build
+            t_dev = time.perf_counter()
+            final = jax.block_until_ready(
+                _group_runner(shared, cfg, mesh, mode)(cells, states))
+            device_s += time.perf_counter() - t_dev
             final = jax.tree_util.tree_map(np.asarray, final)
 
             for j, i in enumerate(chunk_idxs[:ncells]):
@@ -324,4 +337,5 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                                         stats_fg=fg, stats_bg=bg)
 
     return SweepReport(results, len(specs), len(group_cells),
-                       time.perf_counter() - t0, group_cells)
+                       time.perf_counter() - t0, group_cells,
+                       build_s=build_s, device_s=device_s)

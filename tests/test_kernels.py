@@ -43,6 +43,14 @@ def test_lcmp_decide_matches_ref_param_sweep(seed):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def test_lcmp_decide_rejects_more_than_8_candidates():
+    F, P = 16, 9
+    z = jnp.zeros((F, P), jnp.int32)
+    with pytest.raises(ValueError, match="at most 8 candidates"):
+        ops.lcmp_decide(jnp.arange(F, dtype=jnp.uint32), z, z,
+                        jnp.ones((F, P), bool))
+
+
 def test_lcmp_decide_all_invalid_rows():
     F, P = 130, 4
     fids = jnp.arange(F, dtype=jnp.uint32)
@@ -83,7 +91,8 @@ def test_cong_update_param_sweep():
 
 
 # ------------------------------------------------------------------- qsr_int8
-@pytest.mark.parametrize("n", [1024, 4096, 64 * 1024])
+# 130 scale groups: more than one grid step, padded to a multiple of 128
+@pytest.mark.parametrize("n", [1024, 4096, 64 * 1024, 130 * 1024])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_qsr_int8_matches_ref(n, dtype):
     k1, k2 = jax.random.split(jax.random.key(n))
