@@ -95,6 +95,11 @@ def _cell_line(r):
             f"fg_p50={fg.p50:.6f} fg_p99={fg.p99:.6f}")
 
 
+def _stages(rep):
+    """A sweep's host seconds per stage, as ``lower:1.234,run:5.678``."""
+    return ",".join(f"{k}:{v:.3f}" for k, v in rep.stages.items())
+
+
 def main_phase(specs_by_engine):
     """Batched twice (first call compiles), then sequential; compare."""
     from repro.netsim.sweep import run_sweep
@@ -108,9 +113,8 @@ def main_phase(specs_by_engine):
         _same_cells(first, second, "batched, first vs second call")
         for r in first.results:
             print(f"main {_cell_line(r)} group_cells={len(specs)} "
-                  f"build_s={first.build_s:.3f} "
-                  f"first_call_s={first.device_s:.3f} "
-                  f"second_call_s={second.device_s:.3f} "
+                  f"first_stages={_stages(first)} "
+                  f"second_stages={_stages(second)} "
                   f"sequential_s={seq_s:.3f}", flush=True)
     print("main: batched == sequential bit for bit on every cell",
           flush=True)
@@ -199,9 +203,9 @@ def sharded_phase(specs, ndev):
     plain = run_sweep(specs)
     _same_cells(sharded, plain, f"sharded over {ndev} vs unsharded")
     for r in sharded.results:
-        print(f"sharded {_cell_line(r)} build_s={sharded.build_s:.3f} "
-              f"sharded_call_s={sharded.device_s:.3f} "
-              f"unsharded_call_s={plain.device_s:.3f}", flush=True)
+        print(f"sharded {_cell_line(r)} "
+              f"sharded_stages={_stages(sharded)} "
+              f"unsharded_stages={_stages(plain)}", flush=True)
     print(f"sharded: {len(specs)} cells over {ndev} devices == unsharded "
           "bit for bit", flush=True)
 

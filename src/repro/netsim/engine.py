@@ -242,6 +242,12 @@ class SimState:
     # re-installed from effective capacities (see ``ctrl_refresh``)
     c_path: jnp.ndarray        # (NP,) i32
     redte_w: jnp.ndarray       # (NPAIR, K) i32 split weights
+    # work counters, not part of the answers (``count_live``): the flows
+    # each step moves (active once its arrivals are routed), summed over
+    # steps and at most. int32 is exact while F x steps < 2**31 (the
+    # benchmark's cells reach 4.2e7)
+    live_flow_steps: jnp.ndarray  # () i32
+    live_flow_peak: jnp.ndarray   # () i32
 
 
 # SimState fields with a leading per-flow axis — the sweep engine pads
@@ -418,6 +424,8 @@ def build(table: PathTable, flows: FlowSet, cfg: SimConfig):
         c_cong=jnp.zeros((L,), jnp.int32),
         c_path=c_path,
         redte_w=jnp.ones((NPAIR, K), jnp.int32),
+        live_flow_steps=jnp.int32(0),
+        live_flow_peak=jnp.int32(0),
     )
     return arr, state
 
@@ -490,10 +498,10 @@ def monitor_tick(t, st, ar: SimArrays, cfg: SimConfig):
     cong = congmod.monitor_update(st.cong, qcells, t * cfg.dt_us,
                                   ar.tables, cfg.congp)
     c_cong = congmod.calc_cong_cost(cong, ar.tables, cfg.congp)
-    return dataclasses.replace(
-        st, cong=cong, c_cong=c_cong,
-        hist_c=st.hist_c.at[:, jnp.asarray(t % HIST, jnp.int32)].set(
-            c_cong, mode=RING_SCATTER_MODE))
+    with jax.named_scope("step.rings"):
+        hist_c = st.hist_c.at[:, jnp.asarray(t % HIST, jnp.int32)].set(
+            c_cong, mode=RING_SCATTER_MODE)
+    return dataclasses.replace(st, cong=cong, c_cong=c_cong, hist_c=hist_c)
 
 
 def ctrl_tick(t, st, ar: SimArrays, cfg: SimConfig):
@@ -507,6 +515,21 @@ def ctrl_tick(t, st, ar: SimArrays, cfg: SimConfig):
             st, c_path=jnp.where((t % period) == 0,
                                  ctrl_refresh(t, st, ar, cfg), st.c_path))
     return st
+
+
+def live_flows(st) -> jnp.ndarray:
+    """The flows a step moves: those active once its arrivals are routed
+    (a flow that arrives and completes in one step counts). Padded flows
+    never activate, so a sweep's padding adds nothing."""
+    return st.active.sum(dtype=jnp.int32)
+
+
+def count_live(st, n):
+    """End-of-step work counters: add ``n`` (``live_flows``) to
+    ``live_flow_steps`` and raise ``live_flow_peak`` to it."""
+    return dataclasses.replace(
+        st, live_flow_steps=st.live_flow_steps + n,
+        live_flow_peak=jnp.maximum(st.live_flow_peak, n))
 
 
 def redte_tick(t, st, ar: SimArrays, cfg: SimConfig):

@@ -70,6 +70,9 @@ def make_step(ar: SimArrays, cfg: SimConfig):
     checks_on = sanitize.enabled(cfg)
 
     def step(st: SimState, t):
+        # Each phase runs under a ``step.<phase>`` named scope: op
+        # metadata only, so a device trace can charge each op's time to
+        # its phase.
         # 0) failure injection + lazy fast-failover (paper §3.4): at a
         # trip step, flows pinned to a dead path are treated as "first
         # packets" again and re-hashed onto live candidates. The schedule
@@ -77,18 +80,22 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         # predicate stays unbatched under vmap and the reroute cond is a
         # real branch (paid only at trip steps), not a select.
         if cfg.has_failures:
-            st = dataclasses.replace(st, link_alive=t < ar.link_fail_step)
-            is_trip = (ar.link_fail_step == t).any()
-            st = jax.lax.cond(is_trip,
-                              lambda s: _reroute_dead(t, s, ar, cfg),
-                              lambda s: s, st)
+            with jax.named_scope("step.failover"):
+                st = dataclasses.replace(st, link_alive=t < ar.link_fail_step)
+                is_trip = (ar.link_fail_step == t).any()
+                st = jax.lax.cond(is_trip,
+                                  lambda s: _reroute_dead(t, s, ar, cfg),
+                                  lambda s: s, st)
 
         # 1) switch monitor tick + 1b) control-plane refresh (shared)
-        st = monitor_tick(t, st, ar, cfg)
-        st = ctrl_tick(t, st, ar, cfg)
+        with jax.named_scope("step.monitor"):
+            st = monitor_tick(t, st, ar, cfg)
+        with jax.named_scope("step.ctrl"):
+            st = ctrl_tick(t, st, ar, cfg)
 
         # 2) arrivals + routing decisions (the herd batch)
-        st = _route_arrivals(t, st, ar, cfg)
+        with jax.named_scope("step.route"):
+            st = _route_arrivals(t, st, ar, cfg)
 
         # 2b) mid-flow re-decision epoch (fluid eligibility is a timer:
         # every redecide_period_us all re-decision-capable flows may
@@ -97,73 +104,92 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         # (t is unbatched under vmap, so off-epoch steps pay nothing).
         if wants_redecide(cfg):
             period = max(cfg.redecide_period_us // cfg.dt_us, 1)
-            st = jax.lax.cond(
-                (t % period) == 0,
-                lambda s: redecide_tick(t, s, ar, cfg,
-                                        jnp.ones_like(s.active)),
-                lambda s: s, st)
+            with jax.named_scope("step.redecide"):
+                st = jax.lax.cond(
+                    (t % period) == 0,
+                    lambda s: redecide_tick(t, s, ar, cfg,
+                                            jnp.ones_like(s.active)),
+                    lambda s: s, st)
 
-        # 3) offered load per link
-        pf = st.flow_path
-        links_f = ar.path_links[jnp.maximum(pf, 0)]             # (F,H)
-        links_ok = (links_f >= 0) & st.active[:, None] & (pf >= 0)[:, None]
-        lidx = jnp.maximum(links_f, 0)
-        contrib = jnp.where(links_ok, st.rate[:, None], 0.0)
-        offered = jax.ops.segment_sum(contrib.reshape(-1), lidx.reshape(-1),
-                                      num_segments=L)           # (L,) B/us
+        # 2c) the flows this step moves, counted (not part of the answers)
+        with jax.named_scope("step.counters"):
+            live = engine.live_flows(st)
 
-        # 4) per-link share factor and queue integration. Degradation is
-        # *silent* (an OTN segment loses capacity but stays up): flows stay
-        # pinned and only CC + the switch's congestion registers react —
-        # the scenario the paper's cost model is meant to absorb.
-        cap_nom = ar.link_cap
-        if cfg.has_degrade:
-            cap_nom = cap_nom * jnp.where(t >= ar.link_deg_step,
-                                          ar.link_deg_factor, 1.0)
-        cap = jnp.where(st.link_alive, cap_nom, 1e-9)
-        factor_l = jnp.minimum(1.0, cap / jnp.maximum(offered, 1e-9))
-        served = jnp.minimum(offered, cap)
-        q = jnp.clip(st.q_bytes + (offered - cap) * dt, 0.0,
-                     float(cfg.buffer_bytes * cfg.cap_scale))
-        util = offered / cap
-        hslot = jnp.asarray(t % HIST, jnp.int32)
-        st = dataclasses.replace(
-            st, q_bytes=q,
-            hist_q=st.hist_q.at[:, hslot].set(
-                q, mode=engine.RING_SCATTER_MODE),
-            hist_u=st.hist_u.at[:, hslot].set(
-                util, mode=engine.RING_SCATTER_MODE),
-            u_ewma=st.u_ewma * 0.99 + 0.01 * jnp.minimum(util, 1.0),
-            serv_bytes=st.serv_bytes + served * dt)
+        with jax.named_scope("step.links"):
+            # 3) offered load per link
+            pf = st.flow_path
+            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (F,H)
+            links_ok = (links_f >= 0) & st.active[:, None] & (pf >= 0)[:, None]
+            lidx = jnp.maximum(links_f, 0)
+            contrib = jnp.where(links_ok, st.rate[:, None], 0.0)
+            offered = jax.ops.segment_sum(contrib.reshape(-1),
+                                          lidx.reshape(-1),
+                                          num_segments=L)       # (L,) B/us
+
+            # 4) per-link share factor and queue integration. Degradation
+            # is *silent* (an OTN segment loses capacity but stays up):
+            # flows stay pinned and only CC + the switch's congestion
+            # registers react — the scenario the paper's cost model is
+            # meant to absorb.
+            cap_nom = ar.link_cap
+            if cfg.has_degrade:
+                cap_nom = cap_nom * jnp.where(t >= ar.link_deg_step,
+                                              ar.link_deg_factor, 1.0)
+            cap = jnp.where(st.link_alive, cap_nom, 1e-9)
+            factor_l = jnp.minimum(1.0, cap / jnp.maximum(offered, 1e-9))
+            served = jnp.minimum(offered, cap)
+            q = jnp.clip(st.q_bytes + (offered - cap) * dt, 0.0,
+                         float(cfg.buffer_bytes * cfg.cap_scale))
+            util = offered / cap
+        with jax.named_scope("step.rings"):
+            hslot = jnp.asarray(t % HIST, jnp.int32)
+            hist_q = st.hist_q.at[:, hslot].set(
+                q, mode=engine.RING_SCATTER_MODE)
+            hist_u = st.hist_u.at[:, hslot].set(
+                util, mode=engine.RING_SCATTER_MODE)
+        with jax.named_scope("step.links"):
+            st = dataclasses.replace(
+                st, q_bytes=q, hist_q=hist_q, hist_u=hist_u,
+                u_ewma=st.u_ewma * 0.99 + 0.01 * jnp.minimum(util, 1.0),
+                serv_bytes=st.serv_bytes + served * dt)
 
         # 5) CC rate update from delayed signals
-        st = _cc_update(t, st, ar, cfg, pf, links_f, links_ok)
+        with jax.named_scope("step.cc"):
+            st = _cc_update(t, st, ar, cfg, pf, links_f, links_ok)
 
         # 6) drain flows at bottleneck-shared rate
-        f_factor = jnp.where(links_ok, factor_l[lidx], 1.0).min(-1)
-        send = jnp.where(st.active, st.rate * f_factor, 0.0)
-        remaining = st.remaining - send * dt
+        with jax.named_scope("step.drain"):
+            f_factor = jnp.where(links_ok, factor_l[lidx], 1.0).min(-1)
+            send = jnp.where(st.active, st.rate * f_factor, 0.0)
+            remaining = st.remaining - send * dt
 
-        newly_done = st.active & (remaining <= 0)
-        # completion: propagation + residual queue wait on the path
-        qw_now = jnp.where(links_ok, q[lidx] / ar.link_cap[lidx], 0.0).sum(-1)
-        prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
-        fct = ((t + 1) * dt - ar.f_arr_us + prop
-               + 0.5 * (st.extra_wait + qw_now))
-        st = dataclasses.replace(
-            st,
-            remaining=jnp.maximum(remaining, 0.0),
-            active=st.active & ~newly_done,
-            done=st.done | newly_done,
-            fct_us=jnp.where(newly_done, fct, st.fct_us))
+            newly_done = st.active & (remaining <= 0)
+            # completion: propagation + residual queue wait on the path
+            qw_now = jnp.where(links_ok, q[lidx] / ar.link_cap[lidx],
+                               0.0).sum(-1)
+            prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
+            fct = ((t + 1) * dt - ar.f_arr_us + prop
+                   + 0.5 * (st.extra_wait + qw_now))
+            st = dataclasses.replace(
+                st,
+                remaining=jnp.maximum(remaining, 0.0),
+                active=st.active & ~newly_done,
+                done=st.done | newly_done,
+                fct_us=jnp.where(newly_done, fct, st.fct_us))
 
         # 7) RedTE periodic split-ratio re-optimization (shared tick)
-        st = redte_tick(t, st, ar, cfg)
+        with jax.named_scope("step.redte"):
+            st = redte_tick(t, st, ar, cfg)
 
-        # 8) debug-mode physics invariants (Python gate: the unchecked
+        # 8) the work counters (not part of the answers)
+        with jax.named_scope("step.counters"):
+            st = engine.count_live(st, live)
+
+        # 9) debug-mode physics invariants (Python gate: the unchecked
         # trace carries no extra ops)
         if checks_on:
-            st = sanitize.step_check(t, st, ar, cfg)
+            with jax.named_scope("step.checks"):
+                st = sanitize.step_check(t, st, ar, cfg)
 
         return st, None
 

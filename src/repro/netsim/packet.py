@@ -136,21 +136,27 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         return jax.ops.segment_sum(vals, idx, num_segments=L)
 
     def step(st: PacketState, t):
+        # Each phase runs under a ``step.<phase>`` named scope (op
+        # metadata only), the shared ones as in the fluid engine.
         # 0) failure injection + lazy fast-failover (shared semantics,
         # plus dead-queue cleanup — see _reroute_dead_packet)
         if cfg.has_failures:
-            st = dataclasses.replace(st, link_alive=t < ar.link_fail_step)
-            is_trip = (ar.link_fail_step == t).any()
-            st = jax.lax.cond(is_trip,
-                              lambda s: _reroute_dead_packet(t, s, ar, cfg),
-                              lambda s: s, st)
+            with jax.named_scope("step.failover"):
+                st = dataclasses.replace(st, link_alive=t < ar.link_fail_step)
+                is_trip = (ar.link_fail_step == t).any()
+                st = jax.lax.cond(
+                    is_trip, lambda s: _reroute_dead_packet(t, s, ar, cfg),
+                    lambda s: s, st)
 
         # 1) switch monitor tick + control-plane refresh (shared)
-        st = monitor_tick(t, st, ar, cfg)
-        st = ctrl_tick(t, st, ar, cfg)
+        with jax.named_scope("step.monitor"):
+            st = monitor_tick(t, st, ar, cfg)
+        with jax.named_scope("step.ctrl"):
+            st = ctrl_tick(t, st, ar, cfg)
 
         # 2) arrivals + routing decisions (shared herd batch)
-        st = _route_arrivals(t, st, ar, cfg)
+        with jax.named_scope("step.route"):
+            st = _route_arrivals(t, st, ar, cfg)
 
         # 2b) flowlet re-hash (FatPaths semantics): a flow whose hop
         # queues fully drained >= flowlet_gap_us ago may re-decide — the
@@ -162,57 +168,69 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         # pinned-path program untouched otherwise.
         if wants_redecide(cfg):
             gap_steps = max(cfg.flowlet_gap_us // cfg.dt_us, 1)
-            idle = st.fq.sum(-1) <= 0.0
-            st = redecide_tick(t, st, ar, cfg,
-                               idle & ((t - st.last_tx) >= gap_steps))
+            with jax.named_scope("step.redecide"):
+                idle = st.fq.sum(-1) <= 0.0
+                st = redecide_tick(t, st, ar, cfg,
+                                   idle & ((t - st.last_tx) >= gap_steps))
+
+        # 2c) the flows this step moves, counted (not part of the answers)
+        with jax.named_scope("step.counters"):
+            live = engine.live_flows(st)
 
         # flow/link geometry of the routed flows
-        pf = st.flow_path
-        routed = pf >= 0
-        links_f = ar.path_links[jnp.maximum(pf, 0)]             # (F,H)
-        geom_ok = (links_f >= 0) & routed[:, None]
-        lidx = jnp.maximum(links_f, 0)
+        with jax.named_scope("step.hops"):
+            pf = st.flow_path
+            routed = pf >= 0
+            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (F,H)
+            geom_ok = (links_f >= 0) & routed[:, None]
+            lidx = jnp.maximum(links_f, 0)
 
         # 3) PFC XOFF/XON hysteresis on the instantaneous queue depth;
         # the new state lands in the pause ring at slot t and is read
         # back by upstream transmitters with backward propagation delay.
-        pause = jnp.where(st.q_bytes > xoff, True,
-                          jnp.where(st.q_bytes < xon, False, st.pfc_pause))
-        hist_pause = st.hist_pause.at[:, jnp.asarray(t % HIST,
-                                                     jnp.int32)].set(
-            pause, mode=engine.RING_SCATTER_MODE)
-        st = dataclasses.replace(st, pfc_pause=pause, hist_pause=hist_pause)
-        pause_flat = hist_pause.reshape(-1)
+        with jax.named_scope("step.pfc"):
+            pause = jnp.where(st.q_bytes > xoff, True,
+                              jnp.where(st.q_bytes < xon, False,
+                                        st.pfc_pause))
+        with jax.named_scope("step.rings"):
+            hist_pause = st.hist_pause.at[:, jnp.asarray(t % HIST,
+                                                         jnp.int32)].set(
+                pause, mode=engine.RING_SCATTER_MODE)
+        with jax.named_scope("step.pfc"):
+            st = dataclasses.replace(st, pfc_pause=pause,
+                                     hist_pause=hist_pause)
+            pause_flat = hist_pause.reshape(-1)
 
         # 4) injection: CC-paced credit, rate-BDP window, whole packets.
         # The NIC sits at the ingress switch, so its pause gate reads the
         # first link's *current* XOFF state (zero propagation).
-        act = st.active & routed
-        win = jnp.maximum(st.rate * st.rtt_steps.astype(jnp.float32) * dt,
-                          mtu)
-        inflight = st.fq.sum(-1)
-        credit = jnp.where(act, st.credit + st.rate * dt, 0.0)
-        credit = jnp.minimum(credit, win)            # pause != stored burst
-        avail = jnp.minimum(credit, jnp.clip(win - inflight, 0.0, None))
-        l0 = lidx[:, 0]
-        avail = jnp.where(act & ~pause[l0], avail, 0.0)
-        inject = jnp.where(st.remaining <= avail, st.remaining,
-                           jnp.floor(avail / mtu) * mtu)
-        # ingress buffer space is a hard bound (byte-conserving even when
-        # the delayed PFC gate reacts too late)
-        space0 = jnp.clip(buf - st.q_bytes, 0.0, None)
-        inj_factor = jnp.minimum(1.0, space0 / jnp.maximum(seg(inject, l0),
-                                                           1e-9))
-        scaled = inject * inj_factor[l0]
-        # re-quantize a space-limited injection to whole packets so the
-        # packet model survives buffer pressure (the exact-runt path is
-        # the unscaled branch and stays byte-exact)
-        inject = jnp.where(scaled < inject,
-                           jnp.floor(scaled / mtu) * mtu, inject)
-        st = dataclasses.replace(
-            st,
-            remaining=st.remaining - inject,
-            credit=jnp.where(act, credit - inject, 0.0))
+        with jax.named_scope("step.inject"):
+            act = st.active & routed
+            win = jnp.maximum(
+                st.rate * st.rtt_steps.astype(jnp.float32) * dt, mtu)
+            inflight = st.fq.sum(-1)
+            credit = jnp.where(act, st.credit + st.rate * dt, 0.0)
+            credit = jnp.minimum(credit, win)        # pause != stored burst
+            avail = jnp.minimum(credit, jnp.clip(win - inflight, 0.0, None))
+            l0 = lidx[:, 0]
+            avail = jnp.where(act & ~pause[l0], avail, 0.0)
+            inject = jnp.where(st.remaining <= avail, st.remaining,
+                               jnp.floor(avail / mtu) * mtu)
+            # ingress buffer space is a hard bound (byte-conserving even
+            # when the delayed PFC gate reacts too late)
+            space0 = jnp.clip(buf - st.q_bytes, 0.0, None)
+            inj_factor = jnp.minimum(
+                1.0, space0 / jnp.maximum(seg(inject, l0), 1e-9))
+            scaled = inject * inj_factor[l0]
+            # re-quantize a space-limited injection to whole packets so
+            # the packet model survives buffer pressure (the exact-runt
+            # path is the unscaled branch and stays byte-exact)
+            inject = jnp.where(scaled < inject,
+                               jnp.floor(scaled / mtu) * mtu, inject)
+            st = dataclasses.replace(
+                st,
+                remaining=st.remaining - inject,
+                credit=jnp.where(act, credit - inject, 0.0))
 
         # 5) hop-by-hop store-and-forward under per-link budgets.
         # Serving hops in path order lets a packet cross an idle path
@@ -220,112 +238,130 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         # accumulator keeps every link inside cap x dt no matter how many
         # hop positions it appears at. ``q_now`` tracks intra-slot depth
         # for the buffer-space acceptance factors.
-        cap_nom = ar.link_cap
-        if cfg.has_degrade:
-            cap_nom = cap_nom * jnp.where(t >= ar.link_deg_step,
-                                          ar.link_deg_factor, 1.0)
-        cap = jnp.where(st.link_alive, cap_nom, 1e-9)
-        budget = cap * dt
-        fq = st.fq.at[:, 0].add(inject)
-        served = jnp.zeros((L,), jnp.float32)
-        in_l = seg(inject, l0)                       # arrivals per link
-        q_now = st.q_bytes + in_l
-        delivered_add = jnp.zeros_like(st.delivered)
-        for h in range(H):
-            lh = lidx[:, h]
-            okh = geom_ok[:, h]
-            if h + 1 < H:
-                lnext = links_f[:, h + 1]
-                has_next = lnext >= 0
-                lnextc = jnp.maximum(lnext, 0)
-                # PFC gate: the downstream queue's pause state, read one
-                # backward propagation of THIS link late (the pause frame
-                # travels upstream over hop h's fiber)
-                pd = ar.link_delay_us[lh] // cfg.dt_us
-                pslot = jnp.asarray((t - pd) % HIST, jnp.int32)
-                paused_next = pause_flat[lnextc * HIST + pslot] & has_next
-            else:
-                has_next = jnp.zeros_like(okh)
-                lnextc = lh
-                paused_next = jnp.zeros_like(okh)
-            # in checked mode the PFC send gate routes through the
-            # sanitizer seam (identity in production; the pfc_lossless
-            # mutation corrupts it to prove check_pfc fires)
-            gate = sanitize.pfc_gate(okh, paused_next) if checks_on \
-                else (okh & ~paused_next)
-            sendable = jnp.where(gate, fq[:, h], 0.0)
-            demand = seg(sendable, lh)
-            f_serv = jnp.minimum(1.0, jnp.clip(budget - served, 0.0, None)
-                                 / jnp.maximum(demand, 1e-9))
-            out = sendable * f_serv[lh]
-            # downstream buffer acceptance (delivery is never blocked)
-            offered_in = seg(jnp.where(has_next, out, 0.0), lnextc)
-            f_in = jnp.minimum(1.0, jnp.clip(buf - q_now, 0.0, None)
-                               / jnp.maximum(offered_in, 1e-9))
-            out = out * jnp.where(has_next, f_in[lnextc], 1.0)
-            fwd = jnp.where(has_next, out, 0.0)
-            if checks_on:
-                # pfc_lossless: XOFF downstream => nothing forwarded
-                sanitize.check_pfc(fwd, paused_next)
-            fq = fq.at[:, h].add(-out)
-            if h + 1 < H:
-                fq = fq.at[:, h + 1].add(fwd)
-            served = served + seg(out, lh)
-            in_l = in_l + seg(fwd, lnextc)
-            q_now = q_now - seg(out, lh) + seg(fwd, lnextc)
-            delivered_add = delivered_add + jnp.where(has_next, 0.0, out)
+        with jax.named_scope("step.hops"):
+            cap_nom = ar.link_cap
+            if cfg.has_degrade:
+                cap_nom = cap_nom * jnp.where(t >= ar.link_deg_step,
+                                              ar.link_deg_factor, 1.0)
+            cap = jnp.where(st.link_alive, cap_nom, 1e-9)
+            budget = cap * dt
+            fq = st.fq.at[:, 0].add(inject)
+            served = jnp.zeros((L,), jnp.float32)
+            in_l = seg(inject, l0)                   # arrivals per link
+            q_now = st.q_bytes + in_l
+            delivered_add = jnp.zeros_like(st.delivered)
+            for h in range(H):
+                lh = lidx[:, h]
+                okh = geom_ok[:, h]
+                if h + 1 < H:
+                    lnext = links_f[:, h + 1]
+                    has_next = lnext >= 0
+                    lnextc = jnp.maximum(lnext, 0)
+                    # PFC gate: the downstream queue's pause state, read
+                    # one backward propagation of THIS link late (the
+                    # pause frame travels upstream over hop h's fiber)
+                    pd = ar.link_delay_us[lh] // cfg.dt_us
+                    pslot = jnp.asarray((t - pd) % HIST, jnp.int32)
+                    paused_next = pause_flat[lnextc * HIST + pslot] & has_next
+                else:
+                    has_next = jnp.zeros_like(okh)
+                    lnextc = lh
+                    paused_next = jnp.zeros_like(okh)
+                # in checked mode the PFC send gate routes through the
+                # sanitizer seam (identity in production; the
+                # pfc_lossless mutation corrupts it to prove check_pfc
+                # fires)
+                gate = sanitize.pfc_gate(okh, paused_next) if checks_on \
+                    else (okh & ~paused_next)
+                sendable = jnp.where(gate, fq[:, h], 0.0)
+                demand = seg(sendable, lh)
+                f_serv = jnp.minimum(
+                    1.0, jnp.clip(budget - served, 0.0, None)
+                    / jnp.maximum(demand, 1e-9))
+                out = sendable * f_serv[lh]
+                # downstream buffer acceptance (delivery is never blocked)
+                offered_in = seg(jnp.where(has_next, out, 0.0), lnextc)
+                f_in = jnp.minimum(1.0, jnp.clip(buf - q_now, 0.0, None)
+                                   / jnp.maximum(offered_in, 1e-9))
+                out = out * jnp.where(has_next, f_in[lnextc], 1.0)
+                fwd = jnp.where(has_next, out, 0.0)
+                if checks_on:
+                    # pfc_lossless: XOFF downstream => nothing forwarded
+                    sanitize.check_pfc(fwd, paused_next)
+                fq = fq.at[:, h].add(-out)
+                if h + 1 < H:
+                    fq = fq.at[:, h + 1].add(fwd)
+                served = served + seg(out, lh)
+                in_l = in_l + seg(fwd, lnextc)
+                q_now = q_now - seg(out, lh) + seg(fwd, lnextc)
+                delivered_add = delivered_add + jnp.where(has_next, 0.0, out)
 
-        q_new = seg(jnp.where(geom_ok, fq, 0.0).reshape(-1),
-                    lidx.reshape(-1))
-        # offered-load utilization: standing backlog + every byte that
-        # arrived wanting service this slot, over the service capacity —
-        # exceeds 1 under overload and stays high while PFC-paused
-        # backlog sits unserved, matching the fluid engine's
-        # offered/cap semantics for the HPCC law and RedTE's weights
-        util = (st.q_bytes + in_l) / jnp.maximum(budget, 1e-9)
-        hslot = jnp.asarray(t % HIST, jnp.int32)
-        st = dataclasses.replace(
-            st, fq=fq, q_bytes=q_new,
-            delivered=st.delivered + delivered_add,
-            hist_q=st.hist_q.at[:, hslot].set(
-                q_new, mode=engine.RING_SCATTER_MODE),
-            hist_u=st.hist_u.at[:, hslot].set(
-                util, mode=engine.RING_SCATTER_MODE),
-            u_ewma=st.u_ewma * 0.99 + 0.01 * jnp.minimum(util, 1.0),
-            serv_bytes=st.serv_bytes + served)
+            q_new = seg(jnp.where(geom_ok, fq, 0.0).reshape(-1),
+                        lidx.reshape(-1))
+            # offered-load utilization: standing backlog + every byte
+            # that arrived wanting service this slot, over the service
+            # capacity — exceeds 1 under overload and stays high while
+            # PFC-paused backlog sits unserved, matching the fluid
+            # engine's offered/cap semantics for the HPCC law and RedTE's
+            # weights
+            util = (st.q_bytes + in_l) / jnp.maximum(budget, 1e-9)
+            hslot = jnp.asarray(t % HIST, jnp.int32)
+            delivered = st.delivered + delivered_add
+        with jax.named_scope("step.rings"):
+            hist_q = st.hist_q.at[:, hslot].set(
+                q_new, mode=engine.RING_SCATTER_MODE)
+            hist_u = st.hist_u.at[:, hslot].set(
+                util, mode=engine.RING_SCATTER_MODE)
+        with jax.named_scope("step.hops"):
+            st = dataclasses.replace(
+                st, fq=fq, q_bytes=q_new, delivered=delivered,
+                hist_q=hist_q, hist_u=hist_u,
+                u_ewma=st.u_ewma * 0.99 + 0.01 * jnp.minimum(util, 1.0),
+                serv_bytes=st.serv_bytes + served)
 
         # 5b) flowlet clock: a flow is "transmitting" any slot it injects
         # or still has bytes queued somewhere — the idle gap the flowlet
         # detector measures starts when both go to zero. (inject covers
         # the inject-and-cut-through-in-one-slot case.)
         if wants_redecide(cfg):
-            busy = (inject > 0.0) | (st.fq.sum(-1) > 0.0)
-            st = dataclasses.replace(
-                st, last_tx=jnp.where(busy, jnp.int32(0) + t, st.last_tx))
+            with jax.named_scope("step.redecide"):
+                busy = (inject > 0.0) | (st.fq.sum(-1) > 0.0)
+                st = dataclasses.replace(
+                    st, last_tx=jnp.where(busy, jnp.int32(0) + t,
+                                          st.last_tx))
 
         # 6) CC rate update from the RTT-delayed rings (shared laws)
-        links_ok = geom_ok & st.active[:, None]
-        st = _cc_update(t, st, ar, cfg, pf, links_f, links_ok)
+        with jax.named_scope("step.cc"):
+            links_ok = geom_ok & st.active[:, None]
+            st = _cc_update(t, st, ar, cfg, pf, links_f, links_ok)
 
         # 7) completion by delivery: all bytes injected AND every hop
         # queue fully drained (the final drain is exact in f32: the last
         # service factor is 1, so fq hits 0.0, not an epsilon).
-        newly_done = st.active & (st.remaining <= 0.0) & (st.fq.sum(-1) <= 0.0)
-        prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
-        fct = (t + 1) * dt - ar.f_arr_us + prop
-        st = dataclasses.replace(
-            st,
-            active=st.active & ~newly_done,
-            done=st.done | newly_done,
-            fct_us=jnp.where(newly_done, fct, st.fct_us))
+        with jax.named_scope("step.drain"):
+            newly_done = (st.active & (st.remaining <= 0.0)
+                          & (st.fq.sum(-1) <= 0.0))
+            prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
+            fct = (t + 1) * dt - ar.f_arr_us + prop
+            st = dataclasses.replace(
+                st,
+                active=st.active & ~newly_done,
+                done=st.done | newly_done,
+                fct_us=jnp.where(newly_done, fct, st.fct_us))
 
         # 8) RedTE periodic split-ratio re-optimization (shared tick)
-        st = redte_tick(t, st, ar, cfg)
+        with jax.named_scope("step.redte"):
+            st = redte_tick(t, st, ar, cfg)
 
-        # 9) debug-mode physics invariants (Python gate: the unchecked
+        # 9) the work counters (not part of the answers)
+        with jax.named_scope("step.counters"):
+            st = engine.count_live(st, live)
+
+        # 10) debug-mode physics invariants (Python gate: the unchecked
         # trace carries no extra ops)
         if checks_on:
-            st = sanitize.step_check(t, st, ar, cfg)
+            with jax.named_scope("step.checks"):
+                st = sanitize.step_check(t, st, ar, cfg)
 
         return st, None
 

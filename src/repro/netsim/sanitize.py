@@ -262,6 +262,10 @@ COVERAGE_EXEMPT: Dict[str, str] = {
     "credit": "pacing accumulator bounded by the rate-BDP window of the "
               "rate at injection time; the same step's CC update may "
               "shrink that window, so no post-step bound holds",
+    "live_flow_steps": "work counter (flows each step moves, summed); "
+                       "no answer reads it",
+    "live_flow_peak": "work counter (most flows one step moves); no "
+                      "answer reads it",
 }
 
 

@@ -35,10 +35,11 @@ bounds and drop.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from types import SimpleNamespace
-from typing import List, Optional, Sequence
+from typing import Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +51,12 @@ from repro.netsim import fluid, metrics, sanitize
 from repro.netsim.engine import SimArrays, SimState
 from repro.netsim.experiment import (ExpSpec, build_world, make_flows,
                                      run_experiment, spec_to_cfg)
+from repro.netsim.spans import span
+
+# the batched path's host stages, in call order: one ``sweep.<stage>``
+# span each (``repro.netsim.spans``), together the call's wall time
+STAGES = ("group", "world", "flows", "pad", "lower", "run", "fetch",
+          "score")
 
 
 @jax.tree_util.register_dataclass
@@ -80,6 +87,9 @@ class CellResult:
     # stats_fg == stats and stats_bg is None for all-foreground cells.
     stats_fg: metrics.FCTStats = None
     stats_bg: metrics.FCTStats = None
+    # the scan's work counters (``engine.count_live``): live_flow_steps,
+    # live_flow_peak
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -90,13 +100,36 @@ class SweepReport:
     wall_s: float
     group_cells: List[int]     # cells per compiled group
     # batched path only: host seconds building worlds, flow tables and
-    # padded cell stacks, and seconds in the jitted group calls (trace,
-    # compile and run, each call ended by block_until_ready)
+    # padded cell stacks (stages world + flows + pad)
     build_s: Optional[float] = None
-    device_s: Optional[float] = None
+    # batched path only: host seconds per stage of ``STAGES``
+    stages: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # the cells' counters (live_flow_steps summed, live_flow_peak the
+    # largest) and the slots they fill: flow_slots (padded F x steps x
+    # cells); batched path only: arrivals and arrival_slots (filled
+    # entries and T x A of the padded (T, A) tables) and lowerings (group
+    # programs lowered in the call)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def __iter__(self):
         return iter(self.results)
+
+
+@dataclasses.dataclass(frozen=True)
+class CallRecord:
+    """What a batched ``run_sweep`` call leaves for a reader that did not
+    make it, such as a timer around the call or a profile of it."""
+    start: float               # time.perf_counter() at the call's start
+    end: float                 # ... and at its end
+    stages: Dict[str, float]
+    counters: Dict[str, int]
+    # the call's compiled group programs (their HLO names each op's
+    # ``step.*`` scope); kept for the newest call only
+    programs: tuple = ()
+
+
+# the latest batched calls, newest last
+RECENT: Deque[CallRecord] = collections.deque(maxlen=64)
 
 
 def static_key(spec: ExpSpec):
@@ -186,6 +219,27 @@ def _group_runner(shared: SimArrays, cfg, mesh=None, mode: str = "vmap"):
     return jax.jit(run_cells)
 
 
+def _compile(runner, cells: CellArrays, states: SimState):
+    """The group's program, lowered and compiled before its run so that
+    the run's span holds the run alone. A runner with no ``lower`` (the
+    sanitizer's checkify wrapper) is returned as it is and lowers in its
+    first call."""
+    lower = getattr(runner, "lower", None)
+    return runner if lower is None else lower(cells, states).compile()
+
+
+def _cell_counters(final, j=()) -> Dict[str, int]:
+    """The scan counters of a final state, or of its cell ``j``."""
+    return {k: int(np.asarray(getattr(final, k))[j])
+            for k in ("live_flow_steps", "live_flow_peak")}
+
+
+def _sum_counters(cells: Sequence[Dict[str, int]]) -> Dict[str, int]:
+    return {"live_flow_steps": sum(c["live_flow_steps"] for c in cells),
+            "live_flow_peak": max((c["live_flow_peak"] for c in cells),
+                                  default=0)}
+
+
 def _chunk_by_flows(built, idxs, max_pad_frac: float):
     """Split a group's cells into chunks whose flow counts are within
     ``max_pad_frac`` of the chunk max. Padding a 30%-load cell to an
@@ -236,7 +290,7 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
     """
     t0 = time.perf_counter()
     if sequential:
-        results = []
+        results, flow_slots = [], 0
         for spec in specs:
             stats, util, (_, table, flows, cfg, final) = run_experiment(spec)
             view = SimpleNamespace(
@@ -249,93 +303,120 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                                          overall=stats)
             results.append(CellResult(spec=spec, stats=stats, util=util,
                                       final=view, flows=flows,
-                                      stats_fg=fg, stats_bg=bg))
+                                      stats_fg=fg, stats_bg=bg,
+                                      counters=_cell_counters(final)))
+            flow_slots += flows.num_flows * cfg.num_steps
+        counters = _sum_counters([r.counters for r in results])
+        counters["flow_slots"] = flow_slots
         return SweepReport(results, len(results), len(results),
-                           time.perf_counter() - t0, [1] * len(results))
+                           time.perf_counter() - t0, [1] * len(results),
+                           counters=counters)
 
+    stages: Dict[str, float] = {}
+    counters = {"flow_slots": 0, "arrivals": 0, "arrival_slots": 0,
+                "lowerings": 0}
+    programs = []
     # ---- group by static key, preserving input order within groups
-    groups: dict = {}
-    for i, spec in enumerate(specs):
-        groups.setdefault(static_key(spec), []).append(i)
+    with span("sweep.group", stages):
+        groups: dict = {}
+        for i, spec in enumerate(specs):
+            groups.setdefault(static_key(spec), []).append(i)
 
-    ndev = 1
-    if use_mesh:
-        ndev = min(devices or len(jax.devices()), len(jax.devices()))
+        ndev = 1
+        if use_mesh:
+            ndev = min(devices or len(jax.devices()), len(jax.devices()))
 
     results: List[Optional[CellResult]] = [None] * len(specs)
     group_cells: List[int] = []
-    build_s = device_s = 0.0
     for (topology, cfg), idxs in groups.items():
-        t_build = time.perf_counter()
-        scen, table = build_world(topology)
+        with span("sweep.world", stages):
+            scen, table = build_world(topology)
         eng = enginemod.get_engine(cfg.engine)
         # narrow the dynamic dispatch to the policies actually present
         present = {specs[i].policy for i in idxs}
         cfg = dataclasses.replace(cfg, sweep_policies=tuple(
             p for p in fluid.POLICIES if p in present))
         built = []
-        for i in idxs:
-            spec = specs[i]
-            flows = make_flows(spec, scen, table)
-            # build with the concrete policy so policy_code is baked; the
-            # batched run itself uses the "sweep" meta-policy cfg
-            cell_cfg = dataclasses.replace(cfg, policy=spec.policy)
-            arrs, st = eng.build(table, flows, cell_cfg)
-            built.append((flows, arrs, st))
-
-        build_s += time.perf_counter() - t_build
+        with span("sweep.flows", stages):
+            for i in idxs:
+                spec = specs[i]
+                flows = make_flows(spec, scen, table)
+                # build with the concrete policy so policy_code is baked;
+                # the batched run itself uses the "sweep" meta-policy cfg
+                cell_cfg = dataclasses.replace(cfg, policy=spec.policy)
+                arrs, st = eng.build(table, flows, cell_cfg)
+                built.append((flows, arrs, st))
 
         for chunk, chunk_idxs in _chunk_by_flows(built, idxs, max_pad_frac):
-            t_build = time.perf_counter()
-            group_cells.append(len(chunk))
-            Fmax = max(a.f_arr_us.shape[0] for _, a, _ in chunk)
-            Amax = max(a.arrivals.shape[1] for _, a, _ in chunk)
-            padded = [_pad_cell(a, s, Fmax, Amax) for _, a, s in chunk]
+            with span("sweep.pad", stages):
+                group_cells.append(len(chunk))
+                Fmax = max(a.f_arr_us.shape[0] for _, a, _ in chunk)
+                Amax = max(a.arrivals.shape[1] for _, a, _ in chunk)
+                padded = [_pad_cell(a, s, Fmax, Amax) for _, a, s in chunk]
 
-            mesh = None
-            ncells = len(padded)
-            if ndev > 1:
-                # pad the cell axis to a multiple of the mesh so
-                # shard_map gets equal shards; clones are dropped after
-                mesh = make_host_mesh(data=ndev)
-                while len(padded) % ndev:
-                    padded.append(padded[0])
-            cells = _stack([c for c, _ in padded])
-            states = _stack([s for _, s in padded])
+                mesh = None
+                ncells = len(padded)
+                if ndev > 1:
+                    # pad the cell axis to a multiple of the mesh so
+                    # shard_map gets equal shards; clones are dropped after
+                    mesh = make_host_mesh(data=ndev)
+                    while len(padded) % ndev:
+                        padded.append(padded[0])
+                cells = _stack([c for c, _ in padded])
+                states = _stack([s for _, s in padded])
 
-            # blank the per-cell fields before closure capture: one()
-            # replaces them per cell, so leaving them would only bake
-            # chunk[0]'s (T,A) arrivals + flow tables into the compiled
-            # program as dead constants
-            shared = dataclasses.replace(
-                chunk[0][1], arrivals=None, f_arr_us=None, f_size=None,
-                f_pair=None, f_id=None, policy_code=None)
-            mode = batch_mode
-            if mode == "auto":
-                mode = "vmap" if Fmax <= _VMAP_MAX_FLOWS else "map"
-            build_s += time.perf_counter() - t_build
-            t_dev = time.perf_counter()
-            final = jax.block_until_ready(
-                _group_runner(shared, cfg, mesh, mode)(cells, states))
-            device_s += time.perf_counter() - t_dev
-            final = jax.tree_util.tree_map(np.asarray, final)
+                # blank the per-cell fields before closure capture: one()
+                # replaces them per cell, so leaving them would only bake
+                # chunk[0]'s (T,A) arrivals + flow tables into the
+                # compiled program as dead constants
+                shared = dataclasses.replace(
+                    chunk[0][1], arrivals=None, f_arr_us=None, f_size=None,
+                    f_pair=None, f_id=None, policy_code=None)
+                mode = batch_mode
+                if mode == "auto":
+                    mode = "vmap" if Fmax <= _VMAP_MAX_FLOWS else "map"
+            with span("sweep.lower", stages):
+                prog = _compile(_group_runner(shared, cfg, mesh, mode),
+                                cells, states)
+            with span("sweep.run", stages):
+                final = jax.block_until_ready(prog(cells, states))
+            with span("sweep.fetch", stages):
+                final = jax.tree_util.tree_map(np.asarray, final)
 
-            for j, i in enumerate(chunk_idxs[:ncells]):
-                spec, (flows, _, _) = specs[i], chunk[j]
-                F = flows.num_flows
-                view = SimpleNamespace(done=final.done[j, :F],
-                                       fct_us=final.fct_us[j, :F],
-                                       flow_path=final.flow_path[j, :F],
-                                       serv_bytes=final.serv_bytes[j],
-                                       c_path=final.c_path[j])
-                stats = metrics.fct_stats(view, table, flows, cfg)
-                util = metrics.link_utilization(view, shared, cfg)
-                fg, bg = metrics.fg_bg_stats(view, table, flows, cfg,
-                                             overall=stats)
-                results[i] = CellResult(spec=spec, stats=stats, util=util,
-                                        final=view, flows=flows,
-                                        stats_fg=fg, stats_bg=bg)
+            with span("sweep.score", stages):
+                for j, i in enumerate(chunk_idxs[:ncells]):
+                    spec, (flows, _, _) = specs[i], chunk[j]
+                    F = flows.num_flows
+                    view = SimpleNamespace(done=final.done[j, :F],
+                                           fct_us=final.fct_us[j, :F],
+                                           flow_path=final.flow_path[j, :F],
+                                           serv_bytes=final.serv_bytes[j],
+                                           c_path=final.c_path[j])
+                    stats = metrics.fct_stats(view, table, flows, cfg)
+                    util = metrics.link_utilization(view, shared, cfg)
+                    fg, bg = metrics.fg_bg_stats(view, table, flows, cfg,
+                                                 overall=stats)
+                    results[i] = CellResult(
+                        spec=spec, stats=stats, util=util, final=view,
+                        flows=flows, stats_fg=fg, stats_bg=bg,
+                        counters=_cell_counters(final, j))
+            if isinstance(prog, jax.stages.Compiled):
+                programs.append(prog)
+            counters["flow_slots"] += Fmax * cfg.num_steps * ncells
+            # every flow fills one entry of its cell's (T, A) table
+            counters["arrivals"] += sum(f.num_flows for f, _, _ in chunk)
+            counters["arrival_slots"] += cfg.num_steps * Amax * ncells
+            counters["lowerings"] += 1
 
-    return SweepReport(results, len(specs), len(group_cells),
-                       time.perf_counter() - t0, group_cells,
-                       build_s=build_s, device_s=device_s)
+    counters.update(_sum_counters([r.counters for r in results]))
+    report = SweepReport(
+        results, len(specs), len(group_cells), time.perf_counter() - t0,
+        group_cells,
+        build_s=stages.get("world", 0.0) + stages.get("flows", 0.0)
+        + stages.get("pad", 0.0),
+        stages=stages, counters=counters)
+    if RECENT:
+        RECENT[-1] = dataclasses.replace(RECENT[-1], programs=())
+    RECENT.append(CallRecord(t0, time.perf_counter(), dict(stages),
+                             dict(counters), tuple(programs)))
+    return report

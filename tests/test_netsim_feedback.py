@@ -42,6 +42,8 @@ def _state_two_flows(t, rtt):
         c_cong=jnp.zeros((1,), jnp.int32),
         c_path=jnp.zeros((1,), jnp.int32),
         redte_w=jnp.ones((1, 1), jnp.int32),
+        live_flow_steps=jnp.int32(0),
+        live_flow_peak=jnp.int32(0),
     )
 
 
