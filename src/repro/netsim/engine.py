@@ -16,6 +16,14 @@ Both engines are one jitted ``lax.scan`` over ``SimState`` and share,
 - ``SimConfig`` / ``SimArrays`` / ``SimState`` — the experiment config,
   static device arrays, and the dynamic pytree (the packet engine
   subclasses ``SimState`` with its extra per-hop queue state);
+- the **active-flow window**: a step works on W fixed slots, not on all
+  F flows of the run. A flow takes a slot at its arrival step
+  (``_route_arrivals``) and gives it back the step it completes or
+  loses every path (``retire``/``free_slots``), writing its answers
+  (``done``/``fct_us``/``flow_path``, (F,)) as it goes. W follows the
+  arrivals table (``window_size``); a run whose flows in flight outgrow
+  it runs again on twice the slots (``run_windowed``), and answers do
+  not depend on W;
 - ``build()`` — tables, arrival bucketing, failure/degradation schedule
   folding, signal-delay precomputation, HIST validation;
 - the **signal plane**: the ``core.cong`` register pipeline recorded per
@@ -40,8 +48,9 @@ grid runs on either backend.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Protocol, runtime_checkable
+from typing import Dict, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -212,22 +221,28 @@ class SimConfig:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class SimState:
-    # per flow
-    flow_path: jnp.ndarray     # (F,) i32, -1 until routed
-    remaining: jnp.ndarray     # (F,) f32 bytes
-    rate: jnp.ndarray          # (F,) f32 bytes/us
-    active: jnp.ndarray        # (F,) bool
+    # per flow slot: the step works on a window of W fixed slots, each
+    # free or holding one flow in flight (admitted at its arrival step,
+    # freed the step it completes); W is sized from the arrivals table
+    # (``window_size``), not from the run's flow count F
+    slot_flow: jnp.ndarray     # (W,) i32 flow index, -1 while free
+    slot_path: jnp.ndarray     # (W,) i32 the flow's current path
+    remaining: jnp.ndarray     # (W,) f32 bytes
+    rate: jnp.ndarray          # (W,) f32 bytes/us
+    active: jnp.ndarray        # (W,) bool
+    extra_wait: jnp.ndarray    # (W,) f32 queue-wait component
+    rtt_steps: jnp.ndarray     # (W,) i32
+    route_step: jnp.ndarray    # (W,) i32 step the flow was (re)routed at
+    route_nonce: jnp.ndarray   # (W,) i32 re-decision counter (salts the
+                               # flow's hash key per flowlet/epoch)
+    last_dec: jnp.ndarray      # (W,) i32 step of last MD
+    cc_alpha: jnp.ndarray      # (W,) f32 (DCTCP EWMA)
+    cc_target: jnp.ndarray     # (W,) f32 (DCQCN target rate / fast recovery)
+    prev_delay: jnp.ndarray    # (W,) f32 (TIMELY gradient)
+    # per flow: the answers, written as slots are routed and freed
+    flow_path: jnp.ndarray     # (F,) i32 path (latest re-route), -1 until routed
     done: jnp.ndarray          # (F,) bool
     fct_us: jnp.ndarray        # (F,) f32
-    extra_wait: jnp.ndarray    # (F,) f32 queue-wait component
-    rtt_steps: jnp.ndarray     # (F,) i32
-    route_step: jnp.ndarray    # (F,) i32 step the flow was (re)routed at
-    route_nonce: jnp.ndarray   # (F,) i32 re-decision counter (salts the
-                               # flow's hash key per flowlet/epoch)
-    last_dec: jnp.ndarray      # (F,) i32 step of last MD
-    cc_alpha: jnp.ndarray      # (F,) f32 (DCTCP EWMA)
-    cc_target: jnp.ndarray     # (F,) f32 (DCQCN target rate / fast recovery)
-    prev_delay: jnp.ndarray    # (F,) f32 (TIMELY gradient)
     # per link
     q_bytes: jnp.ndarray       # (L,) f32
     hist_q: jnp.ndarray        # (L, HIST) f32 queue bytes
@@ -248,21 +263,100 @@ class SimState:
     # benchmark's cells reach 4.2e7)
     live_flow_steps: jnp.ndarray  # () i32
     live_flow_peak: jnp.ndarray   # () i32
+    # () bool, sticky: some step had more routed arrivals than free
+    # slots. The run's answers are then incomplete, and ``run_regrowing``
+    # runs it again on a window twice as wide
+    window_overflow: jnp.ndarray
 
 
-# SimState fields with a leading per-flow axis — the sweep engine pads
-# and stacks exactly these when batching cells (the rest is per-link/
-# per-pair and shape-shared across a group). Packet-engine extras are
-# appended here so one list covers both state types; fields absent from
-# a given state dataclass are simply never looked up.
-FLOW_FIELDS = ("flow_path", "remaining", "rate", "active", "done", "fct_us",
+# SimState fields with a leading slot axis (W) — every slot starts free
+# and a freed slot is reset to these fields' pad values. Packet-engine
+# extras are appended here so one list covers both state types; fields
+# absent from a given state dataclass are simply never looked up.
+SLOT_FIELDS = ("slot_flow", "slot_path", "remaining", "rate", "active",
                "extra_wait", "rtt_steps", "route_step", "route_nonce",
                "last_dec", "cc_alpha", "cc_target", "prev_delay",
                # packet engine (see packet.PacketState)
                "fq", "credit", "delivered", "last_tx")
-# per-flow field -> inert pad value (mirrors build()'s init state)
-STATE_PAD = {"flow_path": -1, "route_step": 1 << 20,
-             "last_dec": -(1 << 20), "rtt_steps": 1, "last_tx": 1 << 20}
+# SimState fields with a leading flow axis (F): the answers. The sweep
+# engine pads exactly these when batching cells (the rest is per-slot,
+# per-link or per-pair, and shape-shared across a group)
+FLOW_FIELDS = ("flow_path", "done", "fct_us")
+# slot/flow field -> inert pad value (a free slot; an unrouted flow)
+STATE_PAD = {"slot_flow": -1, "slot_path": -1, "flow_path": -1,
+             "route_step": 1 << 20, "last_dec": -(1 << 20), "rtt_steps": 1,
+             "last_tx": 1 << 20}
+
+
+def window_size(num_flows: int, arrival_width: int) -> int:
+    """Slots of a run's window: ``min(F, max(256, next_pow2(8 x A)))``
+    for F flows and an arrivals table A wide (8 steps' worth of the
+    widest step's arrivals). A window as wide as the flow table always
+    fits."""
+    want = max(256, 8 * arrival_width)
+    return max(1, min(num_flows, 1 << (want - 1).bit_length()))
+
+
+# the widest window a run needed, by ``window_key``: later runs of the
+# same key start there instead of regrowing again
+_WINDOW_FIT: Dict[tuple, int] = {}
+# windows regrown (``run_regrowing``) since import
+REGROWS = collections.Counter()
+
+
+def window_key(cfg: "SimConfig", num_flows: int, arrival_width: int) -> tuple:
+    """What a fitted window is remembered under: the traced program's
+    cfg and the flow-table shapes."""
+    return (cfg, int(num_flows), int(arrival_width))
+
+
+def fitted_window(key: tuple) -> int:
+    """``window_size`` for ``key``'s shapes, or the wider window an
+    earlier run of ``key`` regrew to."""
+    _, num_flows, arrival_width = key
+    return max(window_size(num_flows, arrival_width),
+               min(_WINDOW_FIT.get(key, 0), num_flows))
+
+
+def with_window(state, width: int):
+    """``state`` with its slot fields cut or padded (free slots) to
+    ``width`` slots."""
+    if state.slot_flow.shape[0] == width:
+        return state
+    out = {}
+    for f in dataclasses.fields(type(state)):
+        v = getattr(state, f.name)
+        if f.name in SLOT_FIELDS:
+            v = np.asarray(v)[:width]
+            pad = np.full((width - v.shape[0],) + v.shape[1:],
+                          STATE_PAD.get(f.name, 0), v.dtype)
+            v = jnp.asarray(np.concatenate([v, pad]))
+        out[f.name] = v
+    return type(state)(**out)
+
+
+def run_regrowing(run, key: tuple, width: int):
+    """``run(width)`` -> final state (or a stack of them), run again on
+    twice the slots while some run's window overflowed. A window as wide
+    as the flow table always fits; the width that fit is remembered under
+    ``key``. Returns the final state and its width."""
+    num_flows = key[1]
+    while True:
+        final = run(width)
+        if width >= num_flows or not np.asarray(final.window_overflow).any():
+            return final, width
+        width = min(2 * width, num_flows)
+        _WINDOW_FIT[key] = max(_WINDOW_FIT.get(key, 0), width)
+        REGROWS["regrows"] += 1
+
+
+def run_windowed(run_fn, arrs: "SimArrays", state, cfg: "SimConfig"):
+    """``run_fn(arrs, state)`` on a window wide enough for every flow
+    (``run_regrowing``). Returns the final state."""
+    key = window_key(cfg, state.flow_path.shape[0], arrs.arrivals.shape[1])
+    width = max(state.slot_flow.shape[0], fitted_window(key))
+    return run_regrowing(lambda w: run_fn(arrs, with_window(state, w)),
+                         key, width)[0]
 
 
 @jax.tree_util.register_dataclass
@@ -297,7 +391,8 @@ class SimArrays:
 
 
 def build(table: PathTable, flows: FlowSet, cfg: SimConfig):
-    """Pack numpy tables + flows into device arrays and init state.
+    """Pack numpy tables + flows into device arrays and init state: an
+    empty window of ``fitted_window`` slots and unanswered flows.
 
     Engine-agnostic: returns the base ``SimState``; the packet engine
     wraps it with its extra per-hop queue fields (``packet.build``).
@@ -397,22 +492,25 @@ def build(table: PathTable, flows: FlowSet, cfg: SimConfig):
         tables=tb,
     )
     F = flows.num_flows
+    W = fitted_window(window_key(cfg, F, A))
     NPAIR, K = table.pair_cand.shape
     state = SimState(
+        slot_flow=jnp.full((W,), -1, jnp.int32),
+        slot_path=jnp.full((W,), -1, jnp.int32),
+        remaining=jnp.zeros((W,), jnp.float32),
+        rate=jnp.zeros((W,), jnp.float32),
+        active=jnp.zeros((W,), bool),
+        extra_wait=jnp.zeros((W,), jnp.float32),
+        rtt_steps=jnp.ones((W,), jnp.int32),
+        route_step=jnp.full((W,), 1 << 20, jnp.int32),   # sentinel: unrouted
+        route_nonce=jnp.zeros((W,), jnp.int32),
+        last_dec=jnp.full((W,), -(1 << 20), jnp.int32),
+        cc_alpha=jnp.zeros((W,), jnp.float32),
+        cc_target=jnp.zeros((W,), jnp.float32),
+        prev_delay=jnp.zeros((W,), jnp.float32),
         flow_path=jnp.full((F,), -1, jnp.int32),
-        remaining=jnp.zeros((F,), jnp.float32),
-        rate=jnp.zeros((F,), jnp.float32),
-        active=jnp.zeros((F,), bool),
         done=jnp.zeros((F,), bool),
         fct_us=jnp.zeros((F,), jnp.float32),
-        extra_wait=jnp.zeros((F,), jnp.float32),
-        rtt_steps=jnp.ones((F,), jnp.int32),
-        route_step=jnp.full((F,), 1 << 20, jnp.int32),   # sentinel: unrouted
-        route_nonce=jnp.zeros((F,), jnp.int32),
-        last_dec=jnp.full((F,), -(1 << 20), jnp.int32),
-        cc_alpha=jnp.zeros((F,), jnp.float32),
-        cc_target=jnp.zeros((F,), jnp.float32),
-        prev_delay=jnp.zeros((F,), jnp.float32),
         q_bytes=jnp.zeros((L,), jnp.float32),
         hist_q=jnp.zeros((L, HIST), jnp.float32),
         hist_u=jnp.zeros((L, HIST), jnp.float32),
@@ -426,6 +524,7 @@ def build(table: PathTable, flows: FlowSet, cfg: SimConfig):
         redte_w=jnp.ones((NPAIR, K), jnp.int32),
         live_flow_steps=jnp.int32(0),
         live_flow_peak=jnp.int32(0),
+        window_overflow=jnp.asarray(False),
     )
     return arr, state
 
@@ -520,7 +619,8 @@ def ctrl_tick(t, st, ar: SimArrays, cfg: SimConfig):
 def live_flows(st) -> jnp.ndarray:
     """The flows a step moves: those active once its arrivals are routed
     (a flow that arrives and completes in one step counts). Padded flows
-    never activate, so a sweep's padding adds nothing."""
+    never take a slot, so neither a sweep's padding nor the window's
+    width adds anything."""
     return st.active.sum(dtype=jnp.int32)
 
 
@@ -645,7 +745,14 @@ def decide(t, fid, pair, st: SimState, ar: SimArrays, cfg: SimConfig,
 
 
 def _route_arrivals(t, st: SimState, ar: SimArrays, cfg: SimConfig):
-    """Decide paths for the batch of flows arriving this step."""
+    """Decide paths for the batch of flows arriving this step, and admit
+    the routed ones to the window, in arrival-table order, in the slots
+    past its last occupied one (``compact_slots`` first where those are
+    too few). Flows in flight therefore sit in the window in the order
+    they were admitted, whatever its width: every link sum adds the same
+    terms in the same order. A step with more routed arrivals than free
+    slots admits what fits and sets the sticky ``window_overflow`` flag
+    (``run_windowed`` then runs again wider)."""
     idx = ar.arrivals[t]                        # (A,)
     is_flow = idx >= 0
     fidx = jnp.maximum(idx, 0)
@@ -661,28 +768,79 @@ def _route_arrivals(t, st: SimState, ar: SimArrays, cfg: SimConfig):
 
     rtt = jnp.maximum(2 * ar.path_prop[cpath_sel] // cfg.dt_us, 1)
 
+    A = idx.shape[0]
+    W = st.slot_flow.shape[0]
     F = st.flow_path.shape[0]
+    rank = jnp.cumsum(ok, dtype=jnp.int32) - 1                  # (A,)
+    slot_no = jnp.arange(W, dtype=jnp.int32)
+    tail = jnp.where(st.slot_flow >= 0, slot_no + 1, 0).max()
+    st = jax.lax.cond(tail + rank[-1] >= W, compact_slots, lambda s: s, st)
+    tail = jnp.where(st.slot_flow >= 0, slot_no + 1, 0).max()
+    slot = jnp.where(ok, tail + rank, W)
+    admitted = slot < W
+    # each slot's arrival (-1: none admitted there); pads and unrouted
+    # flows take no slot, so results never depend on the pad width
+    src = jnp.full((W,), -1, jnp.int32).at[slot].set(
+        jnp.arange(A, dtype=jnp.int32), mode="drop")
+    new = src >= 0
+    a = jnp.maximum(src, 0)
 
-    def upd(a, vals, where_ok):
-        # pad slots / no-decision flows scatter out of bounds and drop:
-        # writing a[fidx=0] for pads would race a real flow-0 arrival in
-        # the same batch and make results depend on the pad width (which
-        # the sweep engine varies when stacking cells).
-        return a.at[jnp.where(where_ok, fidx, F)].set(vals, mode="drop")
+    def put(field, vals):
+        return jnp.where(new, vals[a], field)
 
-    st = dataclasses.replace(
+    return dataclasses.replace(
         st,
-        flow_path=upd(st.flow_path, chosen, ok),
-        remaining=upd(st.remaining, ar.f_size[fidx], ok),
-        rate=upd(st.rate, ar.path_cap[cpath_sel], ok),
-        cc_target=upd(st.cc_target, ar.path_cap[cpath_sel], ok),
-        active=upd(st.active, ok, ok),
-        extra_wait=upd(st.extra_wait, qw, ok),
-        rtt_steps=upd(st.rtt_steps, rtt.astype(jnp.int32), ok),
-        route_step=upd(st.route_step,
-                       jnp.full(fidx.shape, 0, jnp.int32) + t, ok),
-    )
-    return st
+        slot_flow=put(st.slot_flow, idx),
+        slot_path=put(st.slot_path, chosen),
+        remaining=put(st.remaining, ar.f_size[fidx]),
+        rate=put(st.rate, ar.path_cap[cpath_sel]),
+        cc_target=put(st.cc_target, ar.path_cap[cpath_sel]),
+        active=st.active | new,
+        extra_wait=put(st.extra_wait, qw),
+        rtt_steps=put(st.rtt_steps, rtt.astype(jnp.int32)),
+        route_step=jnp.where(new, jnp.int32(0) + t, st.route_step),
+        flow_path=st.flow_path.at[jnp.where(admitted, fidx, F)].set(
+            chosen, mode="drop"),
+        window_overflow=st.window_overflow | (ok & ~admitted).any())
+
+
+def _slot_pad(name: str, v, keep):
+    """``v`` where ``keep`` (W,) holds, slot field ``name``'s pad value
+    elsewhere."""
+    k = keep.reshape(keep.shape + (1,) * (v.ndim - 1))
+    return jnp.where(k, v, jnp.asarray(STATE_PAD.get(name, 0), v.dtype))
+
+
+def compact_slots(st):
+    """``st`` with its occupied slots moved, in their order, to the
+    front of the window, and every slot after them free."""
+    occ = st.slot_flow >= 0
+    W = occ.shape[0]
+    src = jnp.full((W,), -1, jnp.int32).at[
+        jnp.where(occ, jnp.cumsum(occ, dtype=jnp.int32) - 1, W)].set(
+        jnp.arange(W, dtype=jnp.int32), mode="drop")
+    keep, at = src >= 0, jnp.maximum(src, 0)
+    return dataclasses.replace(st, **{
+        name: _slot_pad(name, getattr(st, name)[at], keep)
+        for name in SLOT_FIELDS if hasattr(st, name)})
+
+
+def free_slots(st, gone):
+    """``st`` with the slots in ``gone`` (W,) freed: every slot field
+    back to its pad value, as ``build`` starts a slot."""
+    return dataclasses.replace(st, **{
+        name: _slot_pad(name, getattr(st, name), ~gone)
+        for name in SLOT_FIELDS if hasattr(st, name)})
+
+
+def retire(st, finished, fct):
+    """Write the flows of the ``finished`` slots (W,) to the answers —
+    done, with FCT ``fct`` (W,) — and free their slots."""
+    at = jnp.where(finished, st.slot_flow, st.flow_path.shape[0])
+    st = dataclasses.replace(
+        st, done=st.done.at[at].set(True, mode="drop"),
+        fct_us=st.fct_us.at[at].set(fct, mode="drop"))
+    return free_slots(st, finished)
 
 
 def _cc_update(t, st: SimState, ar: SimArrays, cfg: SimConfig,
@@ -735,7 +893,8 @@ def _cc_update(t, st: SimState, ar: SimArrays, cfg: SimConfig,
     kmin = cfg.ecn_kmin_bytes * cfg.cap_scale
     kmax = cfg.ecn_kmax_factor * kmin
     p_mark = jnp.clip((q_sig - kmin) / (kmax - kmin), 0.0, 1.0)
-    u01 = (selmod.fmix32(ar.f_id ^ jnp.uint32(t)).astype(jnp.float32)
+    fid = ar.f_id[jnp.maximum(st.slot_flow, 0)]
+    u01 = (selmod.fmix32(fid ^ jnp.uint32(t)).astype(jnp.float32)
            * (1.0 / 4294967296.0))
     marked = u01 < p_mark
 
@@ -793,18 +952,21 @@ def _cc_update(t, st: SimState, ar: SimArrays, cfg: SimConfig,
 
 def _reroute_dead(t, st: SimState, ar: SimArrays, cfg: SimConfig) -> SimState:
     """Re-decide every active flow whose pinned path lost a link (the
-    data-plane lazy-failover semantics, vectorized over all flows once at
-    the trip step). Failover goes through the shared decision core, so
+    data-plane lazy-failover semantics, vectorized over the window once
+    at the trip step). Failover goes through the shared decision core, so
     every policy — wcmp/ucmp/redte cells in a sweep included — re-decides
-    under its *own* law against the post-trip liveness mask.
+    under its *own* law against the post-trip liveness mask. A flow with
+    no live candidate leaves the network: its slot is freed, and its
+    answer keeps the dead path, not done.
 
     The reroute runs before this step's monitor tick, so slot t is not
     yet written: the freshest signal physics offers here is step t-1."""
-    hop = ar.path_links[jnp.maximum(st.flow_path, 0)]
+    hop = ar.path_links[jnp.maximum(st.slot_path, 0)]
     dead = jnp.where(hop >= 0, ~st.link_alive[jnp.maximum(hop, 0)], False).any(-1)
-    move = st.active & dead & (st.flow_path >= 0)
+    move = st.active & dead & (st.slot_path >= 0)
 
-    k_idx, new_path = decide(t, ar.f_id, ar.f_pair, st, ar, cfg,
+    sf = jnp.maximum(st.slot_flow, 0)
+    k_idx, new_path = decide(t, ar.f_id[sf], ar.f_pair[sf], st, ar, cfg,
                              sig_step=t - 1)
     ok = move & (k_idx >= 0)
     npad = jnp.maximum(new_path, 0)
@@ -812,9 +974,12 @@ def _reroute_dead(t, st: SimState, ar: SimArrays, cfg: SimConfig) -> SimState:
     # packets" again — target line rate of the NEW path, a fresh MD
     # timer, and the new path's standing-queue wait (not the dead one's)
     qw = _path_queue_wait(st, ar, npad)
-    return dataclasses.replace(
+    F = st.flow_path.shape[0]
+    st = dataclasses.replace(
         st,
-        flow_path=jnp.where(ok, new_path, st.flow_path),
+        slot_path=jnp.where(ok, new_path, st.slot_path),
+        flow_path=st.flow_path.at[jnp.where(ok, st.slot_flow, F)].set(
+            new_path, mode="drop"),
         rate=jnp.where(ok, ar.path_cap[npad], st.rate),
         cc_target=jnp.where(ok, ar.path_cap[npad], st.cc_target),
         last_dec=jnp.where(ok, jnp.int32(-(1 << 20)), st.last_dec),
@@ -824,8 +989,8 @@ def _reroute_dead(t, st: SimState, ar: SimArrays, cfg: SimConfig) -> SimState:
         rtt_steps=jnp.where(
             ok, jnp.maximum(2 * ar.path_prop[npad]
                             // cfg.dt_us, 1).astype(jnp.int32), st.rtt_steps),
-        route_step=jnp.where(ok, jnp.int32(0) + t, st.route_step),
-        active=jnp.where(move & (k_idx < 0), False, st.active))
+        route_step=jnp.where(ok, jnp.int32(0) + t, st.route_step))
+    return free_slots(st, move & (k_idx < 0))
 
 
 def wants_redecide(cfg: SimConfig) -> bool:
@@ -857,7 +1022,7 @@ def redecide_tick(t, st: SimState, ar: SimArrays, cfg: SimConfig,
     standing-queue wait) follows the new path. The feedback gate in
     ``_cc_update`` then holds rates steady until the new path's own
     signals are a full RTT old."""
-    move = st.active & (st.flow_path >= 0) & eligible & (t > st.route_step)
+    move = st.active & (st.slot_path >= 0) & eligible & (t > st.route_step)
     if cfg.policy == "sweep":
         # only re-decision-capable cells may move; others stay pinned
         # bit-for-bit even when sharing the trace with fatpaths/lcmp_r
@@ -870,16 +1035,20 @@ def redecide_tick(t, st: SimState, ar: SimArrays, cfg: SimConfig,
         return st
 
     nonce = st.route_nonce + move.astype(jnp.int32)
-    fid = ar.f_id ^ selmod.fmix32(nonce.astype(jnp.uint32))
-    k_idx, new_path = decide(t, fid, ar.f_pair, st, ar, cfg)
-    changed = move & (k_idx >= 0) & (new_path != st.flow_path)
+    sf = jnp.maximum(st.slot_flow, 0)
+    fid = ar.f_id[sf] ^ selmod.fmix32(nonce.astype(jnp.uint32))
+    k_idx, new_path = decide(t, fid, ar.f_pair[sf], st, ar, cfg)
+    changed = move & (k_idx >= 0) & (new_path != st.slot_path)
     npad = jnp.maximum(new_path, 0)
     qw = _path_queue_wait(st, ar, npad)
     rtt = jnp.maximum(2 * ar.path_prop[npad] // cfg.dt_us, 1).astype(jnp.int32)
+    F = st.flow_path.shape[0]
     return dataclasses.replace(
         st,
         route_nonce=nonce,
-        flow_path=jnp.where(changed, new_path, st.flow_path),
+        slot_path=jnp.where(changed, new_path, st.slot_path),
+        flow_path=st.flow_path.at[jnp.where(changed, st.slot_flow, F)].set(
+            new_path, mode="drop"),
         rtt_steps=jnp.where(changed, rtt, st.rtt_steps),
         route_step=jnp.where(changed, jnp.int32(0) + t, st.route_step),
         extra_wait=jnp.where(changed, qw, st.extra_wait))

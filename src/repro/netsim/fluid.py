@@ -117,8 +117,8 @@ def make_step(ar: SimArrays, cfg: SimConfig):
 
         with jax.named_scope("step.links"):
             # 3) offered load per link
-            pf = st.flow_path
-            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (F,H)
+            pf = st.slot_path
+            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (W,H)
             links_ok = (links_f >= 0) & st.active[:, None] & (pf >= 0)[:, None]
             lidx = jnp.maximum(links_f, 0)
             contrib = jnp.where(links_ok, st.rate[:, None], 0.0)
@@ -157,7 +157,8 @@ def make_step(ar: SimArrays, cfg: SimConfig):
         with jax.named_scope("step.cc"):
             st = _cc_update(t, st, ar, cfg, pf, links_f, links_ok)
 
-        # 6) drain flows at bottleneck-shared rate
+        # 6) drain flows at bottleneck-shared rate; a finished flow's
+        # FCT goes to the answers and its slot is freed
         with jax.named_scope("step.drain"):
             f_factor = jnp.where(links_ok, factor_l[lidx], 1.0).min(-1)
             send = jnp.where(st.active, st.rate * f_factor, 0.0)
@@ -168,14 +169,11 @@ def make_step(ar: SimArrays, cfg: SimConfig):
             qw_now = jnp.where(links_ok, q[lidx] / ar.link_cap[lidx],
                                0.0).sum(-1)
             prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
-            fct = ((t + 1) * dt - ar.f_arr_us + prop
+            arr_us = ar.f_arr_us[jnp.maximum(st.slot_flow, 0)]
+            fct = ((t + 1) * dt - arr_us + prop
                    + 0.5 * (st.extra_wait + qw_now))
-            st = dataclasses.replace(
-                st,
-                remaining=jnp.maximum(remaining, 0.0),
-                active=st.active & ~newly_done,
-                done=st.done | newly_done,
-                fct_us=jnp.where(newly_done, fct, st.fct_us))
+            st = engine.retire(dataclasses.replace(st, remaining=remaining),
+                               newly_done, fct)
 
         # 7) RedTE periodic split-ratio re-optimization (shared tick)
         with jax.named_scope("step.redte"):
@@ -212,7 +210,12 @@ _run_jit = jax.jit(run_impl, static_argnames=("cfg",))
 def run(arrs: SimArrays, state: SimState, cfg: SimConfig) -> SimState:
     """Single-experiment entry: the plain jit, or the checkify-wrapped
     sanitizer program when ``cfg.checks`` is set (raises
-    ``checkify.JaxRuntimeError`` on an invariant violation)."""
+    ``checkify.JaxRuntimeError`` on an invariant violation), run again
+    on a wider window where the flows in flight outgrew it
+    (``engine.run_windowed``)."""
     if sanitize.enabled(cfg):
-        return sanitize.run_with_checks(run_impl, arrs, state, cfg)
-    return _run_jit(arrs, state, cfg)
+        return engine.run_windowed(
+            lambda a, s: sanitize.run_with_checks(run_impl, a, s, cfg),
+            arrs, state, cfg)
+    return engine.run_windowed(lambda a, s: _run_jit(a, s, cfg),
+                               arrs, state, cfg)

@@ -70,12 +70,13 @@ name = "packet"
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PacketState(SimState):
-    """``SimState`` plus the packet data plane. In-flight bytes of flow
-    ``f`` are exactly ``fq[f].sum()`` — injected but not yet delivered."""
-    fq: jnp.ndarray          # (F, H) f32 bytes queued at each hop egress
-    credit: jnp.ndarray      # (F,) f32 pacing credit (fractional packets)
-    delivered: jnp.ndarray   # (F,) f32 bytes delivered at destination
-    last_tx: jnp.ndarray     # (F,) i32 last slot the flow had bytes in
+    """``SimState`` plus the packet data plane, per slot of the window.
+    In-flight bytes of the flow in slot ``w`` are exactly ``fq[w].sum()``
+    — injected but not yet delivered."""
+    fq: jnp.ndarray          # (W, H) f32 bytes queued at each hop egress
+    credit: jnp.ndarray      # (W,) f32 pacing credit (fractional packets)
+    delivered: jnp.ndarray   # (W,) f32 bytes delivered at destination
+    last_tx: jnp.ndarray     # (W,) i32 last slot the flow had bytes in
                              # flight (flowlet idle-gap detection; only
                              # maintained when the re-decision plane is on)
     pfc_pause: jnp.ndarray   # (L,) bool current XOFF state
@@ -86,16 +87,16 @@ class PacketState(SimState):
 def build(table: PathTable, flows: FlowSet, cfg: SimConfig):
     """Shared ``engine.build`` plus zero-initialized packet state."""
     arr, base = engine.build(table, flows, cfg)
-    F = base.flow_path.shape[0]
+    W = base.slot_flow.shape[0]
     L = base.q_bytes.shape[0]
     H = arr.path_links.shape[1]
     state = PacketState(
         **{f.name: getattr(base, f.name)
            for f in dataclasses.fields(SimState)},
-        fq=jnp.zeros((F, H), jnp.float32),
-        credit=jnp.zeros((F,), jnp.float32),
-        delivered=jnp.zeros((F,), jnp.float32),
-        last_tx=jnp.full((F,), 1 << 20, jnp.int32),  # sentinel: never sent
+        fq=jnp.zeros((W, H), jnp.float32),
+        credit=jnp.zeros((W,), jnp.float32),
+        delivered=jnp.zeros((W,), jnp.float32),
+        last_tx=jnp.full((W,), 1 << 20, jnp.int32),  # sentinel: never sent
                                                      # (t - last_tx < 0 so a
                                                      # routed-but-quiet flow
                                                      # is not flowlet-eligible)
@@ -110,16 +111,17 @@ def _reroute_dead_packet(t, st: PacketState, ar: SimArrays,
     """Lazy failover with packet-queue cleanup: the shared reroute
     re-decides paths/CC; bytes stranded in the dead path's queues are
     treated as lost-and-retransmitted (go-back-N) — returned to
-    ``remaining`` so the flow re-sends them on the new path."""
-    old_path, old_active = st.flow_path, st.active
+    ``remaining`` so the flow re-sends them on the new path. (A flow
+    with no live candidate leaves with its slot, queues and all.)"""
+    old_path = st.slot_path
     st2 = _reroute_dead(t, st, ar, cfg)
-    moved = old_active & ((st2.flow_path != old_path) | ~st2.active)
+    moved = st2.active & (st2.slot_path != old_path)
     stranded = st.fq.sum(-1)
     return dataclasses.replace(
         st2,
         remaining=jnp.where(moved, st2.remaining + stranded, st2.remaining),
-        fq=jnp.where(moved[:, None], 0.0, st.fq),
-        credit=jnp.where(moved, 0.0, st.credit))
+        fq=jnp.where(moved[:, None], 0.0, st2.fq),
+        credit=jnp.where(moved, 0.0, st2.credit))
 
 
 def make_step(ar: SimArrays, cfg: SimConfig):
@@ -179,9 +181,9 @@ def make_step(ar: SimArrays, cfg: SimConfig):
 
         # flow/link geometry of the routed flows
         with jax.named_scope("step.hops"):
-            pf = st.flow_path
+            pf = st.slot_path
             routed = pf >= 0
-            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (F,H)
+            links_f = ar.path_links[jnp.maximum(pf, 0)]         # (W,H)
             geom_ok = (links_f >= 0) & routed[:, None]
             lidx = jnp.maximum(links_f, 0)
 
@@ -337,17 +339,15 @@ def make_step(ar: SimArrays, cfg: SimConfig):
 
         # 7) completion by delivery: all bytes injected AND every hop
         # queue fully drained (the final drain is exact in f32: the last
-        # service factor is 1, so fq hits 0.0, not an epsilon).
+        # service factor is 1, so fq hits 0.0, not an epsilon). A
+        # finished flow's FCT goes to the answers and its slot is freed.
         with jax.named_scope("step.drain"):
             newly_done = (st.active & (st.remaining <= 0.0)
                           & (st.fq.sum(-1) <= 0.0))
             prop = ar.path_prop[jnp.maximum(pf, 0)].astype(jnp.float32)
-            fct = (t + 1) * dt - ar.f_arr_us + prop
-            st = dataclasses.replace(
-                st,
-                active=st.active & ~newly_done,
-                done=st.done | newly_done,
-                fct_us=jnp.where(newly_done, fct, st.fct_us))
+            arr_us = ar.f_arr_us[jnp.maximum(st.slot_flow, 0)]
+            fct = (t + 1) * dt - arr_us + prop
+            st = engine.retire(st, newly_done, fct)
 
         # 8) RedTE periodic split-ratio re-optimization (shared tick)
         with jax.named_scope("step.redte"):
@@ -382,7 +382,12 @@ _run_jit = jax.jit(run_impl, static_argnames=("cfg",))
 def run(arrs: SimArrays, state: PacketState, cfg: SimConfig) -> PacketState:
     """Single-experiment entry: the plain jit, or the checkify-wrapped
     sanitizer program when ``cfg.checks`` is set (raises
-    ``checkify.JaxRuntimeError`` on an invariant violation)."""
+    ``checkify.JaxRuntimeError`` on an invariant violation), run again
+    on a wider window where the flows in flight outgrew it
+    (``engine.run_windowed``)."""
     if sanitize.enabled(cfg):
-        return sanitize.run_with_checks(run_impl, arrs, state, cfg)
-    return _run_jit(arrs, state, cfg)
+        return engine.run_windowed(
+            lambda a, s: sanitize.run_with_checks(run_impl, a, s, cfg),
+            arrs, state, cfg)
+    return engine.run_windowed(lambda a, s: _run_jit(a, s, cfg),
+                               arrs, state, cfg)

@@ -93,19 +93,20 @@ def _check_buffer_bound(t, st, ar: SimArrays, cfg: SimConfig) -> None:
 
 
 def _check_byte_conservation(t, st, ar: SimArrays, cfg: SimConfig) -> None:
-    """Per routed flow, bytes are conserved. Fluid: remaining only ever
-    moves from f_size toward 0. Packet: injected = queued + delivered,
-    i.e. remaining + fq.sum + delivered == f_size — the identity survives
-    go-back-N failover because stranded queue bytes return to
-    ``remaining`` (see ``packet._reroute_dead_packet``)."""
-    routed = st.flow_path >= 0
+    """Per routed flow in the window, bytes are conserved. Fluid:
+    remaining only ever moves from f_size toward 0. Packet: injected =
+    queued + delivered, i.e. remaining + fq.sum + delivered == f_size —
+    the identity survives go-back-N failover because stranded queue
+    bytes return to ``remaining`` (see ``packet._reroute_dead_packet``)."""
+    routed = st.slot_path >= 0
+    size = ar.f_size[jnp.maximum(st.slot_flow, 0)]
     if hasattr(st, "fq"):
         total = st.remaining + st.fq.sum(-1) + st.delivered
-        slack = _REL_EPS * ar.f_size + 2.0 * float(cfg.mtu_bytes)
-        ok = jnp.abs(total - ar.f_size) <= slack
+        slack = _REL_EPS * size + 2.0 * float(cfg.mtu_bytes)
+        ok = jnp.abs(total - size) <= slack
     else:
-        slack = _REL_EPS * ar.f_size + 1.0
-        ok = (st.remaining >= -1e-3) & (st.remaining <= ar.f_size + slack)
+        slack = _REL_EPS * size + 1.0
+        ok = (st.remaining >= -1e-3) & (st.remaining <= size + slack)
     checkify.check(jnp.all(jnp.where(routed, ok, True)),
                    "byte_conservation: flow byte accounting broken")
 
@@ -127,7 +128,7 @@ def _check_ring_head(t, st, ar: SimArrays, cfg: SimConfig) -> None:
 def _check_clock_monotone(t, st, ar: SimArrays, cfg: SimConfig) -> None:
     """Causality of the per-flow clocks: routing/decision timestamps
     never sit in the future, RTTs are at least one step."""
-    routed = st.flow_path >= 0
+    routed = st.slot_path >= 0
     checkify.check(jnp.all(jnp.where(routed, st.route_step <= t, True)),
                    "clock_monotone: route_step in the future")
     checkify.check(jnp.all(st.last_dec <= t),
@@ -184,9 +185,11 @@ def _check_cong_quantized(t, st, ar: SimArrays, cfg: SimConfig) -> None:
 
 def _check_completion_identity(t, st, ar: SimArrays,
                                cfg: SimConfig) -> None:
-    """A flow is never both done and active, and every completed flow
-    carries a positive FCT (fct >= one slot past its arrival)."""
-    checkify.check(jnp.all(~(st.done & st.active)),
+    """A flow is never both done and active (a finished flow has left
+    the window), and every completed flow carries a positive FCT (fct
+    >= one slot past its arrival)."""
+    done_in_window = st.done[jnp.maximum(st.slot_flow, 0)]
+    checkify.check(jnp.all(~(done_in_window & st.active)),
                    "completion_identity: flow both done and active")
     checkify.check(jnp.all(jnp.where(st.done, st.fct_us > 0.0, True)),
                    "completion_identity: completed flow with FCT <= 0")
@@ -200,6 +203,18 @@ def _check_pfc_lossless(t, st, ar: SimArrays, cfg: SimConfig) -> None:
     coverage table can reference it."""
 
 
+def _check_window_slots(t, st, ar: SimArrays, cfg: SimConfig) -> None:
+    """The window's bookkeeping: an active slot holds a flow, and each
+    occupied slot's path is the path its flow's answer records (both
+    are written together at admission, failover and re-decision)."""
+    sf = st.slot_flow
+    checkify.check(jnp.all(jnp.where(st.active, sf >= 0, True)),
+                   "window_slots: active slot holds no flow")
+    answer = st.flow_path[jnp.maximum(sf, 0)]
+    checkify.check(jnp.all(jnp.where(sf >= 0, answer == st.slot_path, True)),
+                   "window_slots: slot path differs from its flow's answer")
+
+
 INVARIANTS: Dict[str, Callable] = {
     "queue_nonneg": _check_queue_nonneg,
     "buffer_bound": _check_buffer_bound,
@@ -211,13 +226,17 @@ INVARIANTS: Dict[str, Callable] = {
     "cong_quantized": _check_cong_quantized,
     "completion_identity": _check_completion_identity,
     "pfc_lossless": _check_pfc_lossless,
+    "window_slots": _check_window_slots,
 }
 
 # state field -> invariant names that constrain it (reprolint INV001
 # requires every field mutated in the scan to appear here or in
 # COVERAGE_EXEMPT; INV002 cross-validates the names both ways)
 INVARIANT_COVERAGE: Dict[str, Tuple[str, ...]] = {
-    "flow_path": ("byte_conservation", "clock_monotone"),
+    "slot_flow": ("window_slots", "byte_conservation",
+                  "completion_identity"),
+    "slot_path": ("byte_conservation", "clock_monotone", "window_slots"),
+    "flow_path": ("window_slots",),
     "remaining": ("byte_conservation",),
     "rate": ("cc_rate_bounds",),
     "active": ("completion_identity", "cc_rate_bounds"),
@@ -266,6 +285,9 @@ COVERAGE_EXEMPT: Dict[str, str] = {
                        "no answer reads it",
     "live_flow_peak": "work counter (most flows one step moves); no "
                       "answer reads it",
+    "window_overflow": "sticky flag the host reads after the run: a set "
+                       "flag makes it run again on a wider window, so no "
+                       "answer of an overflowed run is kept",
 }
 
 

@@ -16,10 +16,13 @@ every cell. This engine instead:
    load x policy figure grid is ONE group — re-decision-capable policies
    (``engine.REDECIDE_POLICIES``) included, their tick is gated per cell
    by ``policy_code`` so pinned cells sharing the trace stay bit-exact;
-2. pads each group's per-cell arrays (flow tables to the max flow count,
-   arrival buckets to the max per-step batch — both padding-invariant by
-   construction, see ``fluid._route_arrivals``'s out-of-bounds-drop
-   scatter) and stacks them along a leading cell axis;
+2. pads each group's per-cell arrays (flow tables and answers to the
+   max flow count, arrival buckets to the max per-step batch — both
+   padding-invariant by construction: padded flows never arrive, so
+   they never take a slot of the active-flow window) and stacks them
+   along a leading cell axis, every cell with a window of the same
+   width (``engine.fitted_window``; regrown and run again where a
+   cell's flows in flight outgrow it);
 3. runs the whole group as ONE jitted invocation — one trace, one
    compile, one device dispatch — either ``jax.vmap`` over the cell axis
    (dispatch-bound small cells) or a compiled ``jax.lax.map`` loop over
@@ -30,8 +33,7 @@ every cell. This engine instead:
 
 Per-cell results are bit-for-bit identical to the sequential loop (the
 tier-1 suite asserts exact FCT equality): vmap batches the same IEEE ops,
-padded flows never activate, and padded arrival slots scatter out of
-bounds and drop.
+padded flows never activate, and padded arrival slots take no slot.
 """
 from __future__ import annotations
 
@@ -106,9 +108,11 @@ class SweepReport:
     stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     # the cells' counters (live_flow_steps summed, live_flow_peak the
     # largest) and the slots they fill: flow_slots (padded F x steps x
-    # cells); batched path only: arrivals and arrival_slots (filled
-    # entries and T x A of the padded (T, A) tables) and lowerings (group
-    # programs lowered in the call)
+    # cells), window_slots (the window's W x steps x cells) and
+    # window_regrows (runs repeated on a wider window); batched path
+    # only: arrivals and arrival_slots (filled entries and T x A of the
+    # padded (T, A) tables) and lowerings (group programs lowered in the
+    # call, regrown ones included)
     counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def __iter__(self):
@@ -123,8 +127,8 @@ class CallRecord:
     end: float                 # ... and at its end
     stages: Dict[str, float]
     counters: Dict[str, int]
-    # the call's compiled group programs (their HLO names each op's
-    # ``step.*`` scope); kept for the newest call only
+    # the call's compiled group programs, regrown ones included (their
+    # HLO names each op's ``step.*`` scope); kept for the newest call only
     programs: tuple = ()
 
 
@@ -151,17 +155,14 @@ def _pad_tail(a: np.ndarray, n: int, fill) -> np.ndarray:
     return out
 
 
-def _pad_cell(arrs: SimArrays, state: SimState, F: int, A: int):
-    """Pad one built cell to the group's (F, A). Padded flows never appear
-    in ``arrivals`` (pad = -1), never activate, and contribute exact 0.0
-    to every link sum, so results are unchanged. Which fields carry a
-    leading flow axis (and their inert pad values) is the engine core's
-    contract (``engine.FLOW_FIELDS`` — the packet engine's extra state is
-    covered there too, and the state's own dataclass type is rebuilt)."""
+def _pad_cell(arrs: SimArrays, F: int, A: int) -> CellArrays:
+    """One built cell's per-cell arrays padded to the group's (F, A).
+    Padded flows never appear in ``arrivals`` (pad = -1), so they never
+    take a slot of the window and results are unchanged."""
     T = arrs.arrivals.shape[0]
     arrivals = np.full((T, A), -1, np.int32)
     arrivals[:, : arrs.arrivals.shape[1]] = np.asarray(arrs.arrivals)
-    cell = CellArrays(
+    return CellArrays(
         arrivals=jnp.asarray(arrivals),
         f_arr_us=jnp.asarray(_pad_tail(np.asarray(arrs.f_arr_us), F, 0.0)),
         f_size=jnp.asarray(_pad_tail(np.asarray(arrs.f_size), F, 0.0)),
@@ -169,15 +170,23 @@ def _pad_cell(arrs: SimArrays, state: SimState, F: int, A: int):
         f_id=jnp.asarray(_pad_tail(np.asarray(arrs.f_id), F, 0)),
         policy_code=arrs.policy_code,
     )
+
+
+def _pad_state(state: SimState, F: int, W: int) -> SimState:
+    """One built cell's initial state with its answers padded to the
+    group's F and its window cut or widened to W free slots. Which fields
+    carry a flow or a slot axis (and their inert pad values) is the
+    engine core's contract (``engine.FLOW_FIELDS``/``SLOT_FIELDS``; the
+    packet engine's extra state is covered there too, and the state's
+    own dataclass type is rebuilt)."""
     st = {}
     for f in dataclasses.fields(type(state)):
         v = getattr(state, f.name)
         if f.name in enginemod.FLOW_FIELDS:
-            st[f.name] = jnp.asarray(_pad_tail(
-                np.asarray(v), F, enginemod.STATE_PAD.get(f.name, 0)))
-        else:
-            st[f.name] = v            # per-link / per-pair: shared shape
-    return cell, type(state)(**st)
+            v = jnp.asarray(_pad_tail(np.asarray(v), F,
+                                      enginemod.STATE_PAD.get(f.name, 0)))
+        st[f.name] = v            # per-link / per-pair: shared shape
+    return enginemod.with_window(type(state)(**st), W)
 
 
 def _stack(trees):
@@ -288,9 +297,15 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
         ``_VMAP_MAX_FLOWS`` crossover). All modes share one trace per
         chunk and produce bit-identical results.
     """
+    if not sequential and RECENT:
+        # only the newest call keeps its programs: free the last call's
+        # before this one's clock starts (tearing a compiled program
+        # down is host work that no stage of this call owns)
+        RECENT[-1] = dataclasses.replace(RECENT[-1], programs=())
     t0 = time.perf_counter()
+    regrows = enginemod.REGROWS["regrows"]
     if sequential:
-        results, flow_slots = [], 0
+        results, flow_slots, window_slots = [], 0, 0
         for spec in specs:
             stats, util, (_, table, flows, cfg, final) = run_experiment(spec)
             view = SimpleNamespace(
@@ -306,15 +321,18 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                                       stats_fg=fg, stats_bg=bg,
                                       counters=_cell_counters(final)))
             flow_slots += flows.num_flows * cfg.num_steps
+            window_slots += final.slot_flow.shape[0] * cfg.num_steps
         counters = _sum_counters([r.counters for r in results])
-        counters["flow_slots"] = flow_slots
+        counters.update(
+            flow_slots=flow_slots, window_slots=window_slots,
+            window_regrows=enginemod.REGROWS["regrows"] - regrows)
         return SweepReport(results, len(results), len(results),
                            time.perf_counter() - t0, [1] * len(results),
                            counters=counters)
 
     stages: Dict[str, float] = {}
-    counters = {"flow_slots": 0, "arrivals": 0, "arrival_slots": 0,
-                "lowerings": 0}
+    counters = {"flow_slots": 0, "window_slots": 0, "arrivals": 0,
+                "arrival_slots": 0, "lowerings": 0}
     programs = []
     # ---- group by static key, preserving input order within groups
     with span("sweep.group", stages):
@@ -352,7 +370,8 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                 group_cells.append(len(chunk))
                 Fmax = max(a.f_arr_us.shape[0] for _, a, _ in chunk)
                 Amax = max(a.arrivals.shape[1] for _, a, _ in chunk)
-                padded = [_pad_cell(a, s, Fmax, Amax) for _, a, s in chunk]
+                key = enginemod.window_key(cfg, Fmax, Amax)
+                padded = [(_pad_cell(a, Fmax, Amax), s) for _, a, s in chunk]
 
                 mesh = None
                 ncells = len(padded)
@@ -363,7 +382,6 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                     while len(padded) % ndev:
                         padded.append(padded[0])
                 cells = _stack([c for c, _ in padded])
-                states = _stack([s for _, s in padded])
 
                 # blank the per-cell fields before closure capture: one()
                 # replaces them per cell, so leaving them would only bake
@@ -375,13 +393,27 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                 mode = batch_mode
                 if mode == "auto":
                     mode = "vmap" if Fmax <= _VMAP_MAX_FLOWS else "map"
-            with span("sweep.lower", stages):
-                prog = _compile(_group_runner(shared, cfg, mesh, mode),
-                                cells, states)
-            with span("sweep.run", stages):
-                final = jax.block_until_ready(prog(cells, states))
-            with span("sweep.fetch", stages):
-                final = jax.tree_util.tree_map(np.asarray, final)
+
+            def run_group(width):
+                with span("sweep.pad", stages):
+                    states = _stack([_pad_state(s, Fmax, width)
+                                     for _, s in padded])
+                with span("sweep.lower", stages):
+                    prog = _compile(_group_runner(shared, cfg, mesh, mode),
+                                    cells, states)
+                    counters["lowerings"] += 1
+                    if isinstance(prog, jax.stages.Compiled):
+                        programs.append(prog)
+                with span("sweep.run", stages):
+                    final = jax.block_until_ready(prog(cells, states))
+                with span("sweep.fetch", stages):
+                    final = jax.tree_util.tree_map(np.asarray, final)
+                    del states, prog    # freed here, not between spans
+                return final
+
+            # a window some cell's flows in flight outgrew runs again wider
+            final, width = enginemod.run_regrowing(
+                run_group, key, enginemod.fitted_window(key))
 
             with span("sweep.score", stages):
                 for j, i in enumerate(chunk_idxs[:ncells]):
@@ -400,23 +432,20 @@ def run_sweep(specs: Sequence[ExpSpec], sequential: bool = False,
                         spec=spec, stats=stats, util=util, final=view,
                         flows=flows, stats_fg=fg, stats_bg=bg,
                         counters=_cell_counters(final, j))
-            if isinstance(prog, jax.stages.Compiled):
-                programs.append(prog)
             counters["flow_slots"] += Fmax * cfg.num_steps * ncells
+            counters["window_slots"] += width * cfg.num_steps * ncells
             # every flow fills one entry of its cell's (T, A) table
             counters["arrivals"] += sum(f.num_flows for f, _, _ in chunk)
             counters["arrival_slots"] += cfg.num_steps * Amax * ncells
-            counters["lowerings"] += 1
 
     counters.update(_sum_counters([r.counters for r in results]))
+    counters["window_regrows"] = enginemod.REGROWS["regrows"] - regrows
     report = SweepReport(
         results, len(specs), len(group_cells), time.perf_counter() - t0,
         group_cells,
         build_s=stages.get("world", 0.0) + stages.get("flows", 0.0)
         + stages.get("pad", 0.0),
         stages=stages, counters=counters)
-    if RECENT:
-        RECENT[-1] = dataclasses.replace(RECENT[-1], programs=())
     RECENT.append(CallRecord(t0, time.perf_counter(), dict(stages),
                              dict(counters), tuple(programs)))
     return report

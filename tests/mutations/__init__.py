@@ -27,7 +27,7 @@ def _buffer_bound(t, st):
 
 def _byte_conservation(t, st):
     return dataclasses.replace(
-        st, remaining=jnp.where(st.flow_path >= 0,
+        st, remaining=jnp.where(st.slot_path >= 0,
                                 st.remaining + 1e9, st.remaining))
 
 
@@ -37,7 +37,7 @@ def _ring_head(t, st):
 
 def _clock_monotone(t, st):
     return dataclasses.replace(
-        st, route_step=jnp.where(st.flow_path >= 0,
+        st, route_step=jnp.where(st.slot_path >= 0,
                                  t + 10, st.route_step))
 
 
@@ -50,7 +50,13 @@ def _cong_quantized(t, st):
 
 
 def _completion_identity(t, st):
-    return dataclasses.replace(st, done=st.done | st.active)
+    at = jnp.where(st.active, st.slot_flow, st.done.shape[0])
+    return dataclasses.replace(st, done=st.done.at[at].set(True,
+                                                           mode="drop"))
+
+
+def _window_slots(t, st):
+    return dataclasses.replace(st, flow_path=st.flow_path + 1)
 
 
 MUTATIONS = {
@@ -62,4 +68,5 @@ MUTATIONS = {
     "cc_rate_bounds": _cc_rate_bounds,
     "cong_quantized": _cong_quantized,
     "completion_identity": _completion_identity,
+    "window_slots": _window_slots,
 }

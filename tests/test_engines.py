@@ -62,8 +62,11 @@ def test_packet_single_flow_matches_closed_form(policy, size):
     ideal = 6000.0 + size / (100 * 125.0)   # prop(5ms+1ms tail) + serialize
     got = float(final.fct_us[0])
     assert abs(got - ideal) <= cfg.dt_us + 1e-3, (got, ideal)
-    # lossless delivery: every byte of the flow arrived, exactly once
-    assert abs(float(final.delivered[0]) - size) < 1.0
+    # lossless delivery: every byte of the flow arrived, exactly once —
+    # all of them served by its path's last link
+    path = int(final.flow_path[0])
+    last = int(table.path_links[path][int(table.path_len[path]) - 1])
+    assert abs(float(final.serv_bytes[last]) - size) < 1.0
 
 
 def test_packet_queues_lossless_and_buffer_bounded():

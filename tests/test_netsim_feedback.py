@@ -10,19 +10,18 @@ from repro.netsim import fluid
 
 
 def _state_two_flows(t, rtt):
-    """Two identical line-rate flows on link 0; flow 0 routed long ago,
-    flow 1 routed just now. The history ring carries heavy congestion at
-    the delayed-read slot (t - rtt)."""
+    """Two identical line-rate flows on link 0, in slots 0 and 1 of the
+    window; flow 0 routed long ago, flow 1 routed just now. The history
+    ring carries heavy congestion at the delayed-read slot (t - rtt)."""
     hist_q = np.zeros((1, fluid.HIST), np.float32)
     hist_q[0, (t - rtt) % fluid.HIST] = 1e9
     z = jnp.zeros((2,), jnp.float32)
     return fluid.SimState(
-        flow_path=jnp.zeros(2, jnp.int32),
+        slot_flow=jnp.arange(2, dtype=jnp.int32),
+        slot_path=jnp.zeros(2, jnp.int32),
         remaining=jnp.ones(2, jnp.float32) * 1e9,
         rate=jnp.full((2,), 100.0, jnp.float32),
         active=jnp.ones(2, bool),
-        done=jnp.zeros(2, bool),
-        fct_us=z,
         extra_wait=z,
         rtt_steps=jnp.full((2,), rtt, jnp.int32),
         route_step=jnp.asarray([0, t - 1], jnp.int32),
@@ -31,6 +30,9 @@ def _state_two_flows(t, rtt):
         cc_alpha=z,
         cc_target=jnp.full((2,), 100.0, jnp.float32),
         prev_delay=z,
+        flow_path=jnp.zeros(2, jnp.int32),
+        done=jnp.zeros(2, bool),
+        fct_us=z,
         q_bytes=jnp.zeros((1,), jnp.float32),
         hist_q=jnp.asarray(hist_q),
         hist_u=jnp.zeros((1, fluid.HIST), jnp.float32),
@@ -44,6 +46,7 @@ def _state_two_flows(t, rtt):
         redte_w=jnp.ones((1, 1), jnp.int32),
         live_flow_steps=jnp.int32(0),
         live_flow_peak=jnp.int32(0),
+        window_overflow=jnp.asarray(False),
     )
 
 

@@ -5,6 +5,7 @@ flowlet detector must fire only after a genuine idle gap, and the
 amp subflow split must aggregate back to parent flows exactly."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,8 +15,37 @@ from repro.core.select import ecmp_select
 from repro.netsim import fluid, metrics, packet, paths, sweep, topo
 from repro.netsim.engine import (POLICY_CODES, REDECIDE_POLICIES, SimConfig,
                                  attach_link_caps)
-from repro.netsim.experiment import ExpSpec, run_experiment
+from repro.netsim.experiment import ExpSpec, build_experiment, run_experiment
 from repro.traffic.gen import FlowSet, generate
+
+
+def _run_seen(mod, arrs, st, cfg):
+    """``mod``'s scan from ``st``: its final state and, per flow, the
+    most re-decisions it had (``route_nonce``) and the latest step it was
+    routed onto path 1 (``route_step``), read at every step's end while
+    the flow held a slot of the window (-1: never seen)."""
+    step = mod.make_step(arrs, cfg)
+    F = st.flow_path.shape[0]
+
+    def body(carry, t):
+        s, nonce, on1 = carry
+        s, _ = step(s, t)
+        at = jnp.where(s.slot_flow >= 0, s.slot_flow, F)
+        nonce = nonce.at[at].max(s.route_nonce, mode="drop")
+        on1 = on1.at[at].max(jnp.where(s.slot_path == 1, s.route_step, -1),
+                             mode="drop")
+        return (s, nonce, on1), None
+
+    none = jnp.full((F,), -1, jnp.int32)
+    (final, nonce, on1), _ = jax.jit(lambda s: jax.lax.scan(
+        body, (s, none, none), jnp.arange(cfg.num_steps)))(st)
+    return final, np.asarray(nonce), np.asarray(on1)
+
+
+def _max_nonce(spec):
+    _, table, flows, cfg = build_experiment(spec)
+    arrs, st = fluid.build(table, flows, cfg)
+    return int(_run_seen(fluid, arrs, st, cfg)[1].max())
 
 
 # ------------------------------------------------ frozen policy registry
@@ -86,7 +116,7 @@ def test_mixed_sweep_keeps_pinned_cells_exact():
                               np.asarray(solo.flow_path)), specs[i].policy
         if specs[i].policy == "lcmp_r":
             # the armed cell's tick is live (nonce advances at epochs)
-            assert int(np.asarray(solo.route_nonce).max()) > 0
+            assert _max_nonce(specs[i]) > 0
 
 
 def test_sweep_with_new_policies_matches_sequential():
@@ -119,7 +149,9 @@ def _hetero_failover(policy):
     arrs, st = fluid.build(table, flows, cfg)
     dead_p = 0
     st = dataclasses.replace(
-        st, flow_path=jnp.full_like(st.flow_path, dead_p),
+        st, slot_flow=jnp.arange(F, dtype=jnp.int32),
+        slot_path=jnp.full_like(st.slot_path, dead_p),
+        flow_path=jnp.full_like(st.flow_path, dead_p),
         active=jnp.ones_like(st.active),
         remaining=jnp.full_like(st.remaining, 1e6),
         link_alive=st.link_alive.at[int(table.path_first[dead_p])].set(False))
@@ -154,9 +186,9 @@ def test_fluid_lcmp_r_beats_stale_lcmp_tail():
         kw = dict(topology="staleness:deg_ms=60", load=0.4, engine="fluid",
                   duration_us=200_000, seed=seed, sig_delay_scale=4.0)
         lcmp, _, _ = run_experiment(ExpSpec(policy="lcmp", **kw))
-        lr, _, (_, _, _, _, fin) = run_experiment(
-            ExpSpec(policy="lcmp_r", redecide_period_us=10_000, **kw))
-        assert int(np.asarray(fin.route_nonce).max()) > 0   # epoch fired
+        lr_spec = ExpSpec(policy="lcmp_r", redecide_period_us=10_000, **kw)
+        lr, _, _ = run_experiment(lr_spec)
+        assert _max_nonce(lr_spec) > 0                      # epoch fired
         assert lr.completion_rate >= lcmp.completion_rate
         assert lr.p99 <= lcmp.p99 * 1.05, (seed, lr.p99, lcmp.p99)
 
@@ -188,7 +220,7 @@ def _flowlet_run(table, flows, gap_us, degrade=True):
                     horizon_us=1_000_000, flowlet_gap_us=gap_us,
                     ecn_kmin_bytes=2e4, degrade_sched=deg)
     arrs, st = packet.build(table, flows, cfg)
-    return packet.run(arrs, st, cfg)
+    return _run_seen(packet, arrs, st, cfg)
 
 
 def test_packet_flowlet_fires_after_genuine_idle_gap():
@@ -198,14 +230,14 @@ def test_packet_flowlet_fires_after_genuine_idle_gap():
     re-hash actually moves traffic onto the clean path — all of it only
     *after* the degrade hit."""
     table, flows = _flowlet_world()
-    f = _flowlet_run(table, flows, gap_us=800)
-    nonce = np.asarray(f.route_nonce)
+    f, nonce, on1 = _flowlet_run(table, flows, gap_us=800)
     fp = np.asarray(f.flow_path)
     assert (nonce > 0).sum() >= len(nonce) // 2      # detector fired
     moved = fp == 1
     assert moved.any()                               # traffic re-balanced
     deg_step = 5000 // int(f.rtt_steps.dtype.type(200))  # 200us slots
-    assert (np.asarray(f.route_step)[moved] > deg_step).all()
+    seen = on1 >= 0
+    assert seen.any() and (on1[seen] > deg_step).all()
     assert np.asarray(f.done).all()
 
 
@@ -221,16 +253,16 @@ def test_packet_flowlet_needs_idle_not_just_time():
                     size_bytes=np.array([2e5, 2e5]),
                     pair_id=np.zeros(2, np.int32),
                     flow_id=np.array([42, 99], np.uint32))
-    armed = _flowlet_run(table, flows, gap_us=800, degrade=False)
-    off = _flowlet_run(table, flows, gap_us=0, degrade=False)
-    assert int(np.asarray(armed.route_nonce).max()) == 0
+    armed, nonce, _ = _flowlet_run(table, flows, gap_us=800, degrade=False)
+    off, _, _ = _flowlet_run(table, flows, gap_us=0, degrade=False)
+    assert int(nonce.max()) == 0
     assert np.array_equal(np.asarray(armed.fct_us), np.asarray(off.fct_us))
     assert np.array_equal(np.asarray(armed.flow_path),
                           np.asarray(off.flow_path))
     # (b) same congested world as the positive case, threshold too high
     table, flows = _flowlet_world()
-    f = _flowlet_run(table, flows, gap_us=400_000)
-    assert int(np.asarray(f.route_nonce).max()) == 0
+    _, nonce, _ = _flowlet_run(table, flows, gap_us=400_000)
+    assert int(nonce.max()) == 0
 
 
 # --------------------------------------------------- amp subflow plumbing

@@ -157,6 +157,8 @@ def test_reroute_dead_reinitializes_cc_state():
     alive_q = 2e6                                # standing queue, live path
     st = dataclasses.replace(
         st,
+        slot_flow=st.slot_flow.at[0].set(0),
+        slot_path=st.slot_path.at[0].set(dead_p),
         flow_path=st.flow_path.at[0].set(dead_p),
         active=st.active.at[0].set(True),
         remaining=st.remaining.at[0].set(1e8),
@@ -168,7 +170,8 @@ def test_reroute_dead_reinitializes_cc_state():
         q_bytes=st.q_bytes.at[surv_first].set(alive_q),
         link_alive=st.link_alive.at[dead_first].set(False))
     out = fluid._reroute_dead(t, st, arrs, cfg)
-    assert int(out.flow_path[0]) == live_p       # moved to the live path
+    assert int(out.slot_path[0]) == live_p       # moved to the live path
+    assert int(out.flow_path[0]) == live_p       # ... and its answer too
     line = float(arrs.path_cap[live_p])
     assert float(out.rate[0]) == line
     assert float(out.cc_target[0]) == line       # target re-initialized

@@ -97,3 +97,25 @@ def test_engine_scan_compiles(engine, shaped):
         shaped(arrs), shaped(state), cfg).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
+
+
+# the benchmark's wan2000-24 cells (bench/configs, bench/traffic): ~41k
+# flows over 1,000 steps at real link rates
+WAN24 = dict(topology="wan2000:dcs=24,segs=2,chords=12,deg_ms=33,"
+             "deg_factor=0.25", load=0.5, bg_load=0.15, pairs="main",
+             cap_scale=1.0, duration_us=100_000, seed=2100004403)
+
+
+@pytest.mark.parametrize("engine", ["fluid", "packet"])
+def test_windowed_scan_compiles_at_benchmark_size(engine, shaped):
+    """Both windowed scans at the benchmark's wan2000-24 size: F ~ 41k
+    answers, a 1024-slot window, within one chip's memory."""
+    _, table, flows, cfg = build_experiment(ExpSpec(engine=engine, **WAN24))
+    eng = enginemod.get_engine(engine)
+    arrs, state = eng.build(table, flows, cfg)
+    assert state.flow_path.shape[0] > 40_000
+    assert state.slot_flow.shape[0] == 1024
+    compiled = jax.jit(eng.run_impl, static_argnames=("cfg",)).lower(
+        shaped(arrs), shaped(state), cfg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 2**30
