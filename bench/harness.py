@@ -6,7 +6,13 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``. Its
 configuration is ``bench/configs/<config>.json`` (the fabric: scenario
 string, link-rate scale, traffic window) and its traffic is
 ``bench/traffic/<traffic>.json`` (what a user submits: engine, policies,
-pairs, loads, flow sizes). The limits of its comparison are
+pairs, loads, flow sizes). Every key of either that is a field of the
+program's ``ExpSpec`` reaches each spec of the cell's grid; a traffic
+file holds nothing else but the grid's keys, ``policies`` and ``seeds``
+(policies x seeds, from the run's seed up). A configuration may name its
+reference, ``bench/reference/<reference>.py`` (default ``sim``), whose
+``run_cell`` answers each cell of the grid. A cell on more than one chip
+shards its grid over them. The limits of its comparison are
 ``bench/limits/<cell>.json``. A per-layer metric is the
 module ``bench/metrics/<name>.py``, whose ``read(run)`` returns the
 number or None. Nothing here names a cell, a configuration or a metric.
@@ -22,7 +28,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -54,14 +60,24 @@ class Cell:
     chips: int
     params: dict     # the configuration's and the traffic's keys merged
     limits: dict     # number compared -> its limit
+    reference: str = "sim"   # module of bench.reference that answers it
 
     @property
     def policies(self) -> List[str]:
         return list(self.params["policies"])
 
     @property
+    def seeds(self) -> int:
+        return int(self.params.get("seeds", 1))
+
+    def grid(self, seed: int) -> List[Tuple[str, int]]:
+        """(policy, seed) of each cell of the unit at the run's ``seed``."""
+        return [(pol, seed + k) for k in range(self.seeds)
+                for pol in self.policies]
+
+    @property
     def num_cells(self) -> int:
-        return len(self.policies)
+        return len(self.policies) * self.seeds
 
     @property
     def num_steps(self) -> int:
@@ -75,6 +91,14 @@ class Cell:
 
 CONFIG_KEYS = ("topology", "cap_scale", "duration_us")
 TRAFFIC_KEYS = ("engine", "policies", "workload", "pairs", "load")
+GRID_KEYS = ("policies", "seeds")
+# ExpSpec fields that the grid sets, one value per cell
+GRID_SET = ("policy", "seed")
+
+
+def spec_fields() -> frozenset:
+    from repro.netsim.experiment import ExpSpec
+    return frozenset(f.name for f in dataclasses.fields(ExpSpec))
 
 
 def load_cell(name: str, bench: Optional[dict] = None,
@@ -92,24 +116,32 @@ def load_cell(name: str, bench: Optional[dict] = None,
     for k in TRAFFIC_KEYS:
         if k not in traffic:
             raise KeyError(f"traffic {entry['traffic']!r}: missing {k!r}")
-    params = {k: cfg[k] for k in CONFIG_KEYS}
+    fields = spec_fields()
+    params = {k: v for k, v in cfg.items() if k in fields}
+    for k in traffic:
+        if k in GRID_SET or (k not in fields and k not in GRID_KEYS):
+            raise KeyError(f"traffic {entry['traffic']!r}: {k!r} is not a "
+                           f"key of the program's spec or of the grid")
+        if k in params:
+            raise KeyError(f"traffic {entry['traffic']!r}: {k!r} is set "
+                           f"by the configuration {conf['name']!r}")
+    if int(traffic.get("seeds", 1)) < 1:
+        raise ValueError(f"traffic {entry['traffic']!r}: seeds < 1")
     params["dt_us"] = Config.dt_us
     params.update(traffic)
     limits = load_json(root / "bench" / "limits" / f"{name}.json")
     return Cell(name=name, chips=int(entry["chips"]), params=params,
-                limits=limits["limits"])
+                limits=limits["limits"],
+                reference=cfg.get("reference", "sim"))
 
 
 def cell_specs(cell: Cell, seed: int):
-    """The unit's grid at ``seed``: one cell per policy."""
+    """The unit's grid at ``seed``: one spec per (policy, seed), each
+    with every key of the cell that is a field of ``ExpSpec``."""
     from repro.netsim.experiment import ExpSpec
-    p = cell.params
-    return [ExpSpec(topology=p["topology"], workload=p["workload"],
-                    load=p["load"], bg_load=p.get("bg_load", 0.0),
-                    pairs=p["pairs"], policy=pol, engine=p["engine"],
-                    duration_us=p["duration_us"], seed=seed,
-                    cap_scale=p["cap_scale"])
-            for pol in cell.policies]
+    fields = spec_fields()
+    base = {k: v for k, v in cell.params.items() if k in fields}
+    return [ExpSpec(**base, policy=pol, seed=s) for pol, s in cell.grid(seed)]
 
 
 @dataclasses.dataclass
@@ -134,10 +166,12 @@ def fingerprint(result) -> str:
 def run_unit(cell: Cell, specs):
     """One unit: the whole user path of one ``run_sweep`` call over the
     cell's grid (flows and world build, padding, the jitted scan, copy
-    back and scoring). Returns the unit's record and the sweep report."""
+    back and scoring). A cell on several chips shards its grid over
+    them. Returns the unit's record and the sweep report."""
     from repro.netsim.sweep import run_sweep
+    mesh = {"use_mesh": True, "devices": cell.chips} if cell.chips > 1 else {}
     t0 = time.perf_counter()
-    rep = run_sweep(specs)
+    rep = run_sweep(specs, **mesh)
     t1 = time.perf_counter()
     return Unit(start=t0, end=t1, build_s=rep.build_s,
                 prints=[fingerprint(r) for r in rep.results]), rep
@@ -230,10 +264,12 @@ def compare_cell(prog, ref) -> Dict[str, float]:
 
 
 def reference_cells(cell: Cell, seed: int, precision: str = "float64"):
-    """The reference's answer for every cell of the grid, in grid order."""
-    from bench.reference.sim import run_cell
-    return [run_cell(dict(cell.params), pol, seed, precision)
-            for pol in cell.policies]
+    """The reference's answer for every cell of the grid, in grid order.
+    The grid's keys stay here: each cell gets its policy and seed."""
+    ref = importlib.import_module(f"bench.reference.{cell.reference}")
+    params = {k: v for k, v in cell.params.items() if k not in GRID_KEYS}
+    return [ref.run_cell(dict(params), pol, s, precision)
+            for pol, s in cell.grid(seed)]
 
 
 def check(cell: Cell, results, refs):

@@ -5,9 +5,9 @@ It is written from the model, not from the program: no JAX, no XLA, no
 batching or padding. Each step works on the flows in flight only (the
 flows arrived and not done), and the link sums are ``np.bincount``. It
 models what the benchmark's cells run: the ``lcmp``, ``lcmp_w``,
-``ecmp``, ``ucmp`` and ``redte`` policies, DCQCN rate control, and silent
-capacity degradation; a configuration that asks for anything else is
-refused.
+``ecmp``, ``ucmp``, ``wcmp`` and ``redte`` policies, DCQCN rate control,
+and silent capacity degradation; a configuration that asks for anything
+else is refused.
 
 The model of one step ``t`` (``dt`` = 200 us):
 
@@ -19,7 +19,10 @@ The model of one step ``t`` (``dt`` = 200 us):
    are recomputed from the links' effective capacities.
 3. Arrivals: the flows arriving in this step are routed together. A
    path's congestion is the largest ``C_cong`` over its hops, each read
-   as late as its one-way propagation back to the ingress.
+   as late as its one-way propagation back to the ingress. WCMP and
+   RedTE pick by a weighted flow hash: candidate ``i`` where the flow's
+   hash, modulo the weights' sum, first falls below their running sum;
+   WCMP's weights are the candidates' provisioned Gbps, fixed.
 4. Data plane. Fluid: each link scales the flows through it by
    ``min(1, cap / offered)``, integrates its queue, and a flow drains at
    its rate times its path's smallest factor. Packet: flows inject whole
@@ -75,7 +78,7 @@ CTRL_PERIOD_US = 100_000       # C_path re-install
 MTU_BYTES = 1024
 PFC_XOFF_FRAC, PFC_XON_FRAC = 0.7, 0.5
 
-POLICIES = ("lcmp", "lcmp_w", "ecmp", "ucmp", "redte")
+POLICIES = ("lcmp", "lcmp_w", "ecmp", "ucmp", "wcmp", "redte")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -323,8 +326,9 @@ class Reference:
             idx = (np.arange(K)[None, :] + rot[:, None]) % K
             best = np.argmin(np.take_along_axis(cost, idx, 1), axis=1)
             k = np.where(valid.any(1), idx[np.arange(n), best], -1)
-        else:                                                # redte
-            w = np.where(valid, np.maximum(self.redte_w[pair], 1), 0)
+        else:                                                # wcmp, redte
+            w = self.path_gbps[cp] if pol == "wcmp" else self.redte_w[pair]
+            w = np.where(valid, np.maximum(w, 1), 0)
             cum = np.cumsum(w, 1)
             total = cum[:, -1]
             hv = (h >> np.uint32(1)).astype(np.int64) % np.maximum(total, 1)

@@ -3,8 +3,8 @@
 The same steps as the program's `netsim.experiment` module, taken
 from a cell's parameters (a dict read from the benchmark's config and
 traffic files) instead of the program's ``ExpSpec``. Load schedules and
-the training co-simulation overlay are not part of the copy: a cell that
-asks for them is refused here.
+the training co-simulation overlay are not part of the copy: ``sim``
+refuses a cell that asks for them.
 """
 from __future__ import annotations
 
@@ -55,9 +55,6 @@ def background_pair_ids(table, fg_ids) -> list:
 
 def make_flows(cell: dict, seed: int, scen, table):
     """The cell's flow table at ``seed``."""
-    for key in ("load_sched", "cosim_model"):
-        if cell.get(key):
-            raise ValueError(f"the reference does not model {key!r}")
     fg_ids = traffic_pair_ids(cell["pairs"], scen, table)
     bg_load = cell.get("bg_load", 0.0)
     bg_ids = background_pair_ids(table, fg_ids) if bg_load > 0 else None

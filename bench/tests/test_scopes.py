@@ -5,6 +5,7 @@ host-stage readers, and nothing read where the program records nothing.
 import gzip
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -180,6 +181,47 @@ def test_phase_readers_on_the_scoped_cut(monkeypatch):
         want = sum(full.get(s, 0.0) for s in names)
         assert got == pytest.approx(want, rel=0.15), phase
         assert scopes.phase_ms(run, phase) > 0
+
+
+def test_all_seven_readers_on_the_scoped_cut(monkeypatch):
+    """The seven readers of the program's spans, scopes and counters on
+    the scoped cut: the phases from its trace and scope map, the host
+    stages from a call record made of the cut's ``sweep.*`` host spans
+    (the cut holds no counters, so the record's are made by hand)."""
+    tr, smap, meta = _scoped_cut()
+    lo, hi = tr.window
+    stages = {}
+    for (a, b), n in zip(tr.host.tolist(), tr.host_names):
+        if n.startswith("sweep."):
+            key = n[len("sweep."):]
+            stages[key] = stages.get(key, 0.0) + (min(b, hi) - max(a, lo)) \
+                * 1e-9
+    assert stages["lower"] > 0 and stages["run"] > 0
+    counters = {"live_flow_steps": 27113, "flow_slots": 18_000_000}
+    rec = SimpleNamespace(start=2.0, end=3.0, stages=stages,
+                          counters=counters, programs=())
+    monkeypatch.setattr(scopes, "_records", lambda: [rec])
+    monkeypatch.setattr(scopes, "program_scopes", lambda: smap)
+    bench = harness.load_benchmark()
+    cell = harness.load_cell(meta["cell"])
+    traced = harness.Unit(start=0.0, end=(hi - lo) * 1e-9, build_s=0.03,
+                          prints=[])
+    other = harness.Unit(start=1.9, end=3.1, build_s=0.03, prints=[])
+    run = harness.RunData(cell=cell, units=[traced, other], traced=traced,
+                          trace=tr, devices=1, unit_bytes=1e6,
+                          peaks=harness.device_peaks("TPU v5 lite"),
+                          mem_peak_bytes=49_188_352)
+    names = harness.per_layer_names(bench, cell.name)
+    assert set(NEW) <= set(names)
+    got = harness.read_per_layer(bench, run)
+    assert set(got) == set(names)
+    assert got["host_lower_s"]["value"] == stages["lower"]
+    assert got["host_score_s"]["value"] == (stages.get("fetch", 0.0)
+                                            + stages.get("score", 0.0))
+    assert got["scan_live_pct"]["value"] == pytest.approx(
+        100 * 27113 / 18_000_000)
+    phases = sum(got[f"scan_{p}_ms"]["value"] for p in scopes.PHASES)
+    assert 0 < phases <= got["scan_step_ms"]["value"]
 
 
 HLO = """\
